@@ -24,6 +24,7 @@ import json
 import time
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 
 SCHEMA_VERSION = 1
 
@@ -51,6 +52,7 @@ def main() -> None:
                     help="write a Chrome-trace/Perfetto JSON span timeline "
                          "here (enables span tracing for the run)")
     args = ap.parse_args()
+    enable_compile_cache()
     profile = "full" if args.full else args.profile
     fast = profile != "full"
     smoke = profile == "smoke"

@@ -140,7 +140,6 @@ def _tiered_maybe_sharded(key, x2, w, tier, imp, ladder, cfg, block):
     mesh = get_mesh()
     flat_n = x2.shape[0]
     if mesh is not None and mesh.size > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         axes = tuple(a for a in mesh.axis_names)
         n_all = mesh.size
@@ -164,10 +163,10 @@ def _tiered_maybe_sharded(key, x2, w, tier, imp, ladder, cfg, block):
                 return y_l, jax.lax.psum(h_l, axes)
 
             spec = P(axes)
-            y2, hist = shard_map(
+            y2, hist = jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(spec, spec, spec, P(), P()),
-                out_specs=(spec, P()), check_rep=False)(
+                out_specs=(spec, P()), check_vma=False)(
                     x2, tier, imp, key, w)
             return y2, hist
 

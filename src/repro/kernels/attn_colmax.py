@@ -6,6 +6,11 @@ This is the r-schedule driver of MCA (Eq. 9).  Materializing A to take a
 column max would cost O(n^2) memory and defeat flash attention; instead we
 recompute score tiles (like a flash backward pass does) and fold the max.
 Output is per query-head; the ops wrapper reduces over heads.
+
+Block layouts follow Mosaic's rule that a block's last two dims be
+(8, 128)-divisible or equal to the array's: LSE enters as a
+``[B, Hq, Sq, 1]`` column (block ``(bq, 1)``) and the column max leaves
+as a ``[B, Hq, 1, Skv]`` row (block ``(1, bk)``).
 """
 from __future__ import annotations
 
@@ -16,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .mca_matmul import _compiler_params
 from .telemetry import LANE_COUNT, LANE_LAUNCH, lane_inc, tel_shape
 
 
@@ -47,7 +51,7 @@ def _colmax_kernel(q_ref, k_ref, lse_ref, o_ref, *rest,
         k = k_ref[0, 0].astype(jnp.float32)                  # [bk, dh]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        lse = lse_ref[0, 0][:, None]                         # [bq, 1]
+        lse = lse_ref[0, 0]                                  # [bq, 1]
         a = jnp.exp(s - lse)                                 # [bq, bk]
         if causal:
             # diagonal offset skv - sq, as in ref_colmax's tril(k=skv - sq)
@@ -65,7 +69,7 @@ def _colmax_kernel(q_ref, k_ref, lse_ref, o_ref, *rest,
 
     @pl.when(i == nq - 1)
     def _done():
-        o_ref[0, 0] = cm_ref[...][0].astype(o_ref.dtype)
+        o_ref[0, 0] = cm_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "block_q",
@@ -89,8 +93,9 @@ def attn_colmax(q: jax.Array, k: jax.Array, lse: jax.Array, *, scale: float,
     nq, nk = sq // bq, skv // bk
 
     grid = (b, hq, nk, nq)
-    out_specs = pl.BlockSpec((1, 1, bk), lambda bb, h, j, i: (bb, h, j))
-    out_shape = jax.ShapeDtypeStruct((b, hq, skv), jnp.float32)
+    out_specs = pl.BlockSpec((1, 1, 1, bk),
+                             lambda bb, h, j, i: (bb, h, 0, j))
+    out_shape = jax.ShapeDtypeStruct((b, hq, 1, skv), jnp.float32)
     semantics = ("parallel", "parallel", "parallel", "arbitrary")
     if telemetry:
         out_specs = [out_specs,
@@ -106,12 +111,15 @@ def attn_colmax(q: jax.Array, k: jax.Array, lse: jax.Array, *, scale: float,
             pl.BlockSpec((1, 1, bq, dh), lambda bb, h, j, i: (bb, h, i, 0)),
             pl.BlockSpec((1, 1, bk, dh),
                          lambda bb, h, j, i: (bb, h // group, j, 0)),
-            pl.BlockSpec((1, 1, bq), lambda bb, h, j, i: (bb, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda bb, h, j, i: (bb, h, i, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((1, bk), jnp.float32)],
-        compiler_params=_compiler_params(semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )
-    return fn(q, k, lse)
+    out = fn(q, k, lse.reshape(b, hq, sq, 1))
+    if telemetry:
+        return out[0].reshape(b, hq, skv), out[1]
+    return out.reshape(b, hq, skv)

@@ -7,6 +7,10 @@ by the companion kernel in attn_colmax.py — A is never materialized.
 Supports GQA natively: kv tensors keep their own head count and the
 BlockSpec index_map maps query head h -> kv head h // (Hq // Hkv), so
 repeated KV never exists in memory.
+
+The kernel writes LSE as a ``[B, Hq, Sq, 1]`` column (block ``(bq, 1)``):
+Mosaic needs a block's last two dims (8, 128)-divisible or equal to the
+array's, which a ``(1, bq)`` block of ``[B, Hq, Sq]`` is not.
 """
 from __future__ import annotations
 
@@ -17,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .mca_matmul import _compiler_params
 from .telemetry import LANE_COUNT, LANE_LAUNCH, lane_inc, tel_shape
 
 NEG_INF = -1e30
@@ -82,8 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(safe_l))[:, 0].astype(
-            lse_ref.dtype)
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(safe_l)).astype(lse_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "block_q",
@@ -114,11 +116,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kv_map = lambda bb, h, i, j: (bb, h // group, j, 0)
     out_specs = [
         pl.BlockSpec((1, 1, bq, dh), lambda bb, h, i, j: (bb, h, i, 0)),
-        pl.BlockSpec((1, 1, bq), lambda bb, h, i, j: (bb, h, i)),
+        pl.BlockSpec((1, 1, bq, 1), lambda bb, h, i, j: (bb, h, i, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b, hq, sq, dh), q.dtype),
-        jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
+        jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
     ]
     semantics = ("parallel", "parallel", "parallel", "arbitrary")
     if telemetry:
@@ -142,7 +144,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )
-    return fn(q, k, v)
+    out, lse, *tel = fn(q, k, v)
+    return (out, lse.reshape(b, hq, sq), *tel)

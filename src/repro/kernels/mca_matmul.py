@@ -39,13 +39,6 @@ from .telemetry import LANE_COUNT, LANE_LAUNCH, lane_inc, tel_shape
 DEFAULT_BLOCK = 128  # sampled column-block width (lane-aligned)
 
 
-def _compiler_params(dimension_semantics):
-    cp = getattr(pltpu, "CompilerParams", None)
-    if cp is None:  # older jax
-        cp = getattr(pltpu, "TPUCompilerParams")
-    return cp(dimension_semantics=dimension_semantics)
-
-
 # ---------------------------------------------------------------- fixed R
 def _fixed_kernel(s_ref, scale_ref, x_ref, w_ref, o_ref, *rest, n_samples):
     if len(rest) == 2:                    # telemetry output precedes scratch
@@ -122,7 +115,7 @@ def mca_matmul_fixed(x: jax.Array, w: jax.Array, idx: jax.Array,
         functools.partial(_fixed_kernel, n_samples=r),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )
     return fn(idx.astype(jnp.int32), inv_rp.astype(jnp.float32), x, w)
@@ -220,7 +213,7 @@ def mca_matmul_ragged(x: jax.Array, w: jax.Array, r_tile: jax.Array,
         functools.partial(_ragged_kernel, n_samples=r_max),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )
     return fn(r_tile.astype(jnp.int32), idx.astype(jnp.int32),

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On non-TPU backends the kernels run in ``interpret=True`` mode (the kernel
-body executes as jnp on CPU), so the whole framework is testable offline
-while the compiled path targets TPU VMEM/MXU tiling.
+On the CPU backend the kernels run in ``interpret=True`` mode (the kernel
+body executes as jnp), so the whole framework is testable offline; on
+every other backend they compile, so a backend the kernels cannot target
+fails loudly instead of silently interpreting.
 
 Two layers of accounting, deliberately distinct (see ROADMAP § Observability):
 
@@ -42,7 +43,7 @@ from .telemetry import LANE_COUNT, LANE_LAUNCH
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _count(op: str, used_kernel: bool) -> None:
@@ -154,20 +155,18 @@ def kv_slot_update(cache: jax.Array, new: jax.Array, pos: jax.Array
     """Per-row KV-cache write: ``cache[b, pos[b]] = new[b, 0]``.
 
     cache: [B, S, ...]; new: [B, 1, ...] (same trailing dims); pos: [B]
-    int32.  The Pallas kernel folds ``pos`` into the output BlockSpec via
-    scalar prefetch (DMA writes only the B touched rows, in place through
-    ``input_output_aliases``); when the flattened feature size is not
-    lane-aligned the XLA scatter fallback runs instead.
+    int32.  The Pallas kernel DMAs each new row to ``cache[b, pos[b]]``
+    (only the B touched rows are written, in place through
+    ``input_output_aliases``, with no reshape of the cache); when a row
+    is not whole tiles of the cache's layout (``cache_update.row_dma_ok``)
+    the XLA scatter fallback runs instead.
 
     Device telemetry: ``device_rows_written == B`` per execution on both
     paths — a K-step decode burst therefore shows K launches where the
     dispatch counter shows one traced call site.
     """
-    b, s = cache.shape[0], cache.shape[1]
-    f = 1
-    for d in cache.shape[2:]:
-        f *= d
-    use_kernel = f % 128 == 0
+    b = cache.shape[0]
+    use_kernel = _cache_mod.row_dma_ok(cache.shape, cache.dtype)
     _count("kv_slot_update", use_kernel)
     if not use_kernel:
         out = cache.at[jnp.arange(b), pos].set(new[:, 0])
@@ -176,15 +175,13 @@ def kv_slot_update(cache: jax.Array, new: jax.Array, pos: jax.Array
     with obs.trace("kv_slot_update"):
         if devtel.enabled():
             out, tel = _cache_mod.kv_slot_update(
-                cache.reshape(b, s, f), new.reshape(b, 1, f), pos,
-                interpret=_interpret(), telemetry=True)
+                cache, new, pos, interpret=_interpret(), telemetry=True)
             _emit_tel("kv_slot_update", "device_rows_written",
                       tel[0, LANE_LAUNCH], tel[0, LANE_COUNT])
         else:
-            out = _cache_mod.kv_slot_update(
-                cache.reshape(b, s, f), new.reshape(b, 1, f), pos,
-                interpret=_interpret())
-    return out.reshape(cache.shape)
+            out = _cache_mod.kv_slot_update(cache, new, pos,
+                                            interpret=_interpret())
+    return out
 
 
 def flash_attention(q, k, v, *, scale, causal=True, block_q=128, block_k=128):

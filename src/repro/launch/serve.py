@@ -6,10 +6,15 @@
 
 ``--per-slot`` serves with the ``SlotBatcher`` (per-request prefill
 insertion + sync-free decode bursts) instead of the legacy wave batcher.
+Prints the request count per terminal status and exits 1 if any request
+ended ``failed``.
 """
 from __future__ import annotations
 
 import argparse
+import collections
+import dataclasses
+import sys
 import time
 
 import jax
@@ -17,8 +22,27 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.policy import MCAConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model, reduced
 from repro.serve import ContinuousBatcher, Engine, Request, SlotBatcher
+from repro.serve.engine import FAILED
+
+
+def build_server(model, params, *, slots: int, max_len: int, mca: bool,
+                 per_slot: bool = True, check_every: int = 8,
+                 seed: int = 0):
+    """The serving stack: an ``Engine`` over ``slots`` decode slots and the
+    batcher in front of it.  Returns ``(engine, batcher)``."""
+    engine = Engine(model, params, batch_size=slots, max_len=max_len,
+                    mca_enabled=mca, seed=seed)
+    batcher = (SlotBatcher(engine, check_every=check_every) if per_slot
+               else ContinuousBatcher(engine))
+    return engine, batcher
+
+
+def status_counts(batcher) -> dict:
+    """Requests per terminal status (``ok``, ``degraded``, ``failed``...)."""
+    return dict(collections.Counter(batcher.status.values()))
 
 
 def main():
@@ -37,20 +61,19 @@ def main():
     ap.add_argument("--check-every", type=int, default=8,
                     help="decode burst length for --per-slot")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    mca = MCAConfig(enabled=args.mca, alpha=args.alpha, block=16,
-                    sites=("v_proj",))
+    mca = MCAConfig(enabled=args.mca, alpha=args.alpha, sites=("v_proj",))
     cfg = get_config(args.arch, mca=mca)
     if args.reduced:
-        cfg = reduced(cfg, mca=mca)
+        # d_model 128: a 16-wide block leaves MCA several blocks to sample
+        cfg = reduced(cfg, mca=dataclasses.replace(mca, block=16))
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    engine = Engine(model, params, batch_size=args.batch,
-                    max_len=args.max_len, mca_enabled=args.mca)
-    if args.per_slot:
-        batcher = SlotBatcher(engine, check_every=args.check_every)
-    else:
-        batcher = ContinuousBatcher(engine)
+    _, batcher = build_server(model, params, slots=args.batch,
+                              max_len=args.max_len, mca=args.mca,
+                              per_slot=args.per_slot,
+                              check_every=args.check_every)
 
     rng = np.random.default_rng(0)
     t0 = time.time()
@@ -62,10 +85,13 @@ def main():
     done = batcher.run()
     dt = time.time() - t0
     tokens = sum(len(v) for v in done.values())
+    counts = status_counts(batcher)
     print(f"served {len(done)} requests / {tokens} tokens "
-          f"in {dt:.2f}s ({tokens / dt:.1f} tok/s)")
+          f"in {dt:.2f}s ({tokens / dt:.1f} tok/s); by status: {counts}")
     for uid in sorted(done)[:3]:
         print(f"  req {uid}: {done[uid][:8]}...")
+    if counts.get(FAILED):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

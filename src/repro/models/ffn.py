@@ -86,7 +86,6 @@ def moe_ffn(p, cfg, x, *, mca_key=None):
     from repro.dist.context import dp_axes, get_mesh
     mesh = get_mesh()
     if mesh is not None and mesh.size > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         dp = dp_axes(mesh)
         dpe = dp[0] if len(dp) == 1 else dp
@@ -111,11 +110,11 @@ def moe_ffn(p, cfg, x, *, mca_key=None):
                 stats = jax.tree.map(lambda v: jax.lax.psum(v, axes), stats)
                 return y, aux, stats
 
-            return shard_map(
+            return jax.shard_map(
                 local_fn, mesh=mesh,
                 in_specs=(P(), x_spec, P()),
                 out_specs=(x_spec, P(), P()),
-                check_rep=False)(p, x, key)
+                check_vma=False)(p, x, key)
     return _moe_local(p, cfg, x, mca_key)
 
 

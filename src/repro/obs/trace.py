@@ -1,8 +1,7 @@
 """Profiler trace annotations for the MCA hot paths.
 
-Thin wrappers over ``jax.profiler`` so call sites never need to guard on
-profiler availability: if ``TraceAnnotation``/``annotate_function`` are
-missing (old jax, stripped builds), these degrade to no-ops.
+Thin wrappers over ``jax.profiler`` so call sites name their spans in
+one place.
 
 Annotations name trace-time work.  Under ``jax.jit`` the Python body runs
 once per compilation, so a span around jitted code brackets *dispatch*,
@@ -13,31 +12,18 @@ in the profiler timeline.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Callable
 
-try:                                       # pragma: no cover - import guard
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except ImportError:                        # pragma: no cover
-    _TraceAnnotation = None
-
-try:                                       # pragma: no cover - import guard
-    from jax.profiler import annotate_function as _annotate_function
-except ImportError:                        # pragma: no cover
-    _annotate_function = None
+from jax.profiler import TraceAnnotation, annotate_function
 
 
 def trace(name: str):
-    """Context manager emitting a named profiler span (no-op without jax)."""
-    if _TraceAnnotation is None:
-        return contextlib.nullcontext()
-    return _TraceAnnotation(name)
+    """Context manager emitting a named profiler span."""
+    return TraceAnnotation(name)
 
 
 def annotate(name: str) -> Callable:
     """Decorator labelling a function's compiled region in profiler output."""
     def deco(fn: Callable) -> Callable:
-        if _annotate_function is None:
-            return fn
-        return _annotate_function(fn, name=name)
+        return annotate_function(fn, name=name)
     return deco

@@ -29,7 +29,9 @@ def _decay_mask(path) -> bool:
 
 
 def init_state(params):
-    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+    # zeros_like keeps each leaf's sharding, so sharded params get moments
+    # sharded the same way instead of a full f32 copy on one device
+    zeros = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
     return {"m": zeros,
             "v": jax.tree.map(jnp.copy, zeros),
             "count": jnp.zeros((), jnp.int32)}

@@ -305,7 +305,9 @@ class Engine:
         MCA on unless ``mca=False``) and donate/write its K/V pages and
         position state into the shared decode cache at ``slot``.
 
-        Returns ``(state, first_token, s_pad)``.  Raises
+        Returns ``(state, logits, s_pad)``: ``logits`` is the host copy of
+        the last prompt position's logits over the real vocab (its argmax
+        is the first generated token).  Raises
         :class:`resilience.NonFiniteError` when the insertion logits come
         back non-finite (the ``serve.insert`` injection point taps the
         logits first) — the slot's state is still consistently
@@ -348,8 +350,7 @@ class Engine:
             # the (consistent) new state so they can retry into the slot
             e.slot_state = state
             raise
-        first = int(logits_np[0, 0, :self.model.cfg.vocab_size].argmax())
-        return state, first, s_pad
+        return state, logits_np[0, 0, :self.model.cfg.vocab_size], s_pad
 
     def _make_burst(self, k: int, eos_id: Optional[int]):
         model, cfg = self.model, self.model.cfg
@@ -496,7 +497,8 @@ class ContinuousBatcher:
         for attempt in range(self.max_retries):
             reg.counter("resilience.serve.wave_retries").inc()
             log.warning("wave failed (%s); retry %d/%d with exact "
-                        "attention", last, attempt + 1, self.max_retries)
+                        "attention", last, attempt + 1, self.max_retries,
+                        exc_info=last)
             time.sleep(self.backoff_s * (2 ** attempt))
             try:
                 gen = eng.generate(prompts, max_new, prompt_lens=lens,
@@ -568,7 +570,7 @@ class ContinuousBatcher:
                 gen, degraded = self._run_wave(prompts, max_new, lens,
                                                n_real)
             except Exception as e:                         # noqa: BLE001
-                log.error("wave failed after retries: %s", e)
+                log.error("wave failed after retries: %s", e, exc_info=e)
                 for r in real:
                     r.reason = str(e)
                     self._finish(r, FAILED)
@@ -656,10 +658,11 @@ class SlotBatcher(ContinuousBatcher):
             if attempt:
                 reg.counter("resilience.serve.insert_retries").inc()
                 log.warning("insert failed (%s); retry %d/%d with exact "
-                            "attention", last, attempt, self.max_retries)
+                            "attention", last, attempt, self.max_retries,
+                            exc_info=last)
                 time.sleep(self.backoff_s * (2 ** (attempt - 1)))
             try:
-                state, first, s_pad = eng.prefill_into(
+                state, logits, s_pad = eng.prefill_into(
                     req.prompt, state, slot, req.max_new, mca=use_mca)
             except ValueError:
                 raise    # deterministic (capacity): retrying can't help
@@ -669,6 +672,7 @@ class SlotBatcher(ContinuousBatcher):
                 state = getattr(e, "slot_state", state)
                 last = e
                 continue
+            first = int(logits.argmax())
             degraded = attempt > 0 and eng.mca_enabled
             if degraded:
                 reg.counter("resilience.serve.degraded_requests").inc()
@@ -746,7 +750,8 @@ class SlotBatcher(ContinuousBatcher):
                 decode_failures += 1
                 reg.counter("resilience.serve.decode_retries").inc()
                 if decode_failures > self.max_retries:
-                    log.error("decode failed after retries: %s", e)
+                    log.error("decode failed after retries: %s", e,
+                              exc_info=e)
                     for slot in range(b):
                         if slots[slot] is None:
                             continue
@@ -760,7 +765,8 @@ class SlotBatcher(ContinuousBatcher):
                     decode_failures = 0
                 else:
                     log.warning("decode burst failed (%s); retry %d/%d",
-                                e, decode_failures, self.max_retries)
+                                e, decode_failures, self.max_retries,
+                                exc_info=e)
                     time.sleep(self.backoff_s * (2 ** decode_failures))
                 continue
             decode_failures = 0
@@ -818,7 +824,7 @@ class SlotBatcher(ContinuousBatcher):
         log.warning("slot %d produced non-finite logits; restarting with "
                     "exact attention", slot)
         try:
-            state, first, s_pad = eng.prefill_into(
+            state, logits, s_pad = eng.prefill_into(
                 req.prompt, state, slot, req.max_new, mca=False)
         except Exception as e:                             # noqa: BLE001
             state = getattr(e, "slot_state", state)
@@ -826,6 +832,7 @@ class SlotBatcher(ContinuousBatcher):
             self._finish(req, FAILED)
             reg.counter("resilience.serve.failed_requests").inc()
             return eng.kill_slot(state, slot), None
+        first = int(logits.argmax())
         degraded = eng.mca_enabled
         if degraded:
             reg.counter("resilience.serve.degraded_requests").inc()

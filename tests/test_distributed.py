@@ -14,6 +14,7 @@ _SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models import build_model, reduced
     from repro.dist import context as dctx, sharding as shd
     from repro.optim import adamw
@@ -22,7 +23,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.data import SyntheticLM
 
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4))
 
     cfg = reduced(get_config("qwen3-32b"), n_layers=2, d_model=64,
                   n_heads=8, n_kv_heads=4, d_head=16, d_ff=128,
@@ -56,17 +57,52 @@ _SCRIPT = textwrap.dedent("""
     print("OK losses", loss0, loss1)
 """)
 
+_LAUNCH_TRAIN_SCRIPT = textwrap.dedent("""
+    # launch.train.train on a --mesh 2,4 layout: the first-step loss matches
+    # the one-device run, the training state is created split over the
+    # mesh, and the jitted step compiles once across steps.
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import jax, numpy as np
+    from repro.configs import get_config
+    from repro.data import SyntheticLM
+    from repro.launch.mesh import make_mesh, parse_mesh
+    from repro.launch.train import train
+    from repro.models import build_model, reduced
+    from repro.optim import adamw
+    from repro.train import TrainerConfig
+
+    cfg = reduced(get_config("starcoder2-3b"), dtype="float32")
+    model = build_model(cfg)
+    data = SyntheticLM(cfg.vocab_size, 32, 4, seed=0)
+    tcfg = TrainerConfig(total_steps=3, log_every=1, finite_checks=False)
+    opt = adamw.AdamWConfig(lr=1e-3)
+    one = train(model, opt, data, tcfg)[1]["history"][0]["loss"]
+    mesh = make_mesh(parse_mesh("2,4", jax.device_count()))
+    trainer, out = train(model, opt, data, tcfg, mesh=mesh)
+    losses = [h["loss"] for h in out["history"]]
+    assert len(losses) == 3 and np.all(np.isfinite(losses)), losses
+    assert abs(losses[0] - one) <= 1e-5 * abs(one), (losses[0], one)
+    assert trainer.train_step._cache_size() == 1, "step compiled twice"
+    w = trainer.params["layers"]["ffn"]["w_up"]
+    m = trainer.opt_state["m"]["layers"]["ffn"]["w_up"]
+    for a in (w, m):
+        assert a.addressable_shards[0].data.size * 4 <= a.size, a.sharding
+    print("OK launch train", one, losses)
+""")
+
 _MCA_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config
     from repro.core.policy import MCAConfig
+    from repro.launch.mesh import make_mesh
     from repro.models import build_model, reduced
     from repro.dist import context as dctx, sharding as shd
     from repro.data import SyntheticLM
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4))
     cfg = reduced(get_config("qwen3-32b"), n_layers=2, d_model=64,
                   n_heads=8, n_kv_heads=4, d_head=16, d_ff=128,
                   vocab_size=256,
@@ -102,8 +138,9 @@ _SHARD_SAMPLING_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.core.policy import MCAConfig, mca_project
     from repro.dist import context as dctx
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_mesh((2,), ("data",))
     n, d, f = 16, 256, 64
     half = n // 2
     kx, kw = jax.random.split(jax.random.PRNGKey(0))
@@ -204,6 +241,12 @@ def _run(script: str) -> str:
 def test_sharded_train_step_8dev():
     out = _run(_SCRIPT)
     assert "OK losses" in out
+
+
+@pytest.mark.slow
+def test_launch_train_mesh_8dev():
+    out = _run(_LAUNCH_TRAIN_SCRIPT)
+    assert "OK launch train" in out
 
 
 @pytest.mark.slow

@@ -174,9 +174,9 @@ def test_tiered_dispatch_kernel_path_matches_jnp():
 
 # -------------------------------------------------------- kv_slot_update
 @pytest.mark.parametrize("b,s,trail", [
-    (4, 16, (2, 64)),       # GQA-shaped [B,S,hkv,dh], f=128 -> kernel path
-    (1, 8, (256,)),         # MLA latent [B,S,dl], kernel path
-    (3, 12, (5, 7)),        # f=35: not lane-aligned -> scatter fallback
+    (4, 16, (2, 128)),      # GQA-shaped [B,S,hkv,dh]: whole tiles -> kernel
+    (1, 8, (256,)),         # MLA latent [B,S,dl]: rows split a tile -> scatter
+    (3, 12, (5, 7)),        # not lane-aligned -> scatter fallback
 ])
 def test_kv_slot_update_per_row_write(b, s, trail):
     from repro.kernels import kv_slot_update
@@ -192,12 +192,12 @@ def test_kv_slot_update_per_row_write(b, s, trail):
 
 
 def test_kv_slot_update_dispatch_counters():
-    """Lane-aligned feature dims take the Pallas kernel; others fall back
+    """Rows that are whole tiles take the Pallas kernel; others fall back
     to the XLA scatter — recorded at dispatch time in repro.obs."""
     from repro import obs
     from repro.kernels import kv_slot_update
     with obs.scoped() as reg:
-        kv_slot_update(jnp.zeros((2, 4, 128)), jnp.ones((2, 1, 128)),
+        kv_slot_update(jnp.zeros((2, 4, 1, 128)), jnp.ones((2, 1, 1, 128)),
                        jnp.zeros(2, jnp.int32))
         kv_slot_update(jnp.zeros((2, 4, 5)), jnp.ones((2, 1, 5)),
                        jnp.zeros(2, jnp.int32))
@@ -232,8 +232,8 @@ class TestDeviceTelemetry:
                 def body(c, i):
                     return kv_slot_update(c, new, pos + i), ()
                 return jax.lax.scan(body, cache, jnp.arange(steps))[0]
-            d = self._deltas(lambda: burst(jnp.zeros((b, s, f)),
-                                           jnp.ones((b, 1, f)),
+            d = self._deltas(lambda: burst(jnp.zeros((b, s, 1, f)),
+                                           jnp.ones((b, 1, 1, f)),
                                            jnp.zeros(b, jnp.int32)))
         assert d["kernels.kv_slot_update.device_launches"] == steps
         assert d["kernels.kv_slot_update.device_rows_written"] == steps * b
@@ -385,7 +385,7 @@ class TestDeviceTelemetry:
             def one(cache, new, pos):
                 from repro.kernels import kv_slot_update
                 return kv_slot_update(cache, new, pos)
-            args = (jnp.zeros((b, s, f)), jnp.ones((b, 1, f)),
+            args = (jnp.zeros((b, s, 1, f)), jnp.ones((b, 1, 1, f)),
                     jnp.zeros(b, jnp.int32))
             jax.block_until_ready(one(*args))    # activity BEFORE scope
             devtel.sync()

@@ -9,7 +9,7 @@ from repro.configs import SHAPES, cells, get_config
 from repro.launch import hlo_analysis
 from repro.launch.dryrun import (_depth_overrides, _real_units, model_flops,
                                  n_params, roofline_terms)
-from repro.launch.mesh import HW
+from repro.launch.mesh import HW, parse_mesh
 from repro.launch.specs import input_specs
 
 jax.config.update("jax_platform_name", "cpu")
@@ -125,3 +125,16 @@ class TestRooflineMath:
         f_dec = model_flops(get_config("starcoder2-3b"), "decode",
                             32768, 128)
         assert f_train > f_dec * 1000
+
+
+class TestParseMesh:
+    @pytest.mark.parametrize("spec,n,want", [(None, 4, (4, 1)),
+                                             ("1,4", 4, (1, 4)),
+                                             ("2,4", 8, (2, 4))])
+    def test_shapes(self, spec, n, want):
+        assert parse_mesh(spec, n) == want
+
+    @pytest.mark.parametrize("spec", ["2,4", "4", "1,2,2"])
+    def test_rejects_wrong_device_count(self, spec):
+        with pytest.raises(ValueError):
+            parse_mesh(spec, 4)

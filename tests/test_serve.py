@@ -216,11 +216,13 @@ def test_slot_batcher_eos_and_deadline(engine_setup):
     rng = np.random.default_rng(9)
     p = rng.integers(1, cfg.vocab_size, 6).astype(np.int32)
     ref = eng.generate(np.stack([p, p]), 8)[0].tolist()
-    eos = ref[2]                  # force EOS at the 3rd generated token
-    sb = SlotBatcher(eng, check_every=3, eos_id=eos)
+    # EOS = the first decoded (not inserted) token that is new in ref, so
+    # generation must stop exactly there and not at an earlier repeat
+    i = next(i for i in range(1, len(ref)) if ref[i] not in ref[:i])
+    sb = SlotBatcher(eng, check_every=3, eos_id=ref[i])
     sb.submit(Request(uid=0, prompt=p, max_new=8))
     done = sb.run()
-    assert done[0] == ref[:3], "generation must stop at (and include) EOS"
+    assert done[0] == ref[:i + 1], "generation must stop at (and include) EOS"
 
     sb2 = SlotBatcher(eng, check_every=3)
     sb2.submit(Request(uid=1, prompt=p, max_new=8, deadline_s=-1.0))

@@ -11,6 +11,7 @@ from repro.checkpoint import checkpoint as ckpt
 from repro.configs import get_config
 from repro.data import MemmapLM, Prefetcher, SyntheticLM, write_token_file
 from repro.dist import compress
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, reduced
 from repro.optim import adamw
 from repro.train import Trainer, TrainerConfig, make_train_step
@@ -137,7 +138,7 @@ class TestCheckpoint:
     def test_elastic_restore_with_shardings(self, tmp_path):
         """Restore onto explicit (1-device) shardings — the reshard path."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1))
         tree = self._tree()
         ckpt.save(str(tmp_path), 1, tree)
         sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
@@ -235,15 +236,14 @@ class TestGradCompression:
 
     def test_shard_map_psum_compressed(self):
         """psum_compressed under shard_map on a 1-device mesh."""
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
-        mesh = jax.make_mesh((1,), ("data",))
+        from jax.sharding import PartitionSpec as P
+        mesh = make_mesh((1,), ("data",))
         g = {"w": jnp.ones((8,))}
         e = compress.init_error_buffer(g)
 
         def f(g, e):
             return compress.psum_compressed(g, e, "data")
 
-        out, new_e = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                               out_specs=(P(), P()))(g, e)
+        out, new_e = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                   out_specs=(P(), P()))(g, e)
         np.testing.assert_allclose(np.asarray(out["w"]), 1.0, rtol=1e-2)
