@@ -63,7 +63,7 @@ def main() -> None:
     tables = {}
     fig1 = None
     roofline_summary = None
-    with obs.scoped(reg), obs.trace("benchmarks.run"):
+    with obs.scoped(reg), obs.span("benchmarks.run"):
         from . import kernel_bench
         with obs.span("kernel_bench", cat="bench", track="bench"):
             kb = kernel_bench.run(fast=fast)

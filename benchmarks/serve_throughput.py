@@ -62,7 +62,7 @@ def _serve(batcher_cls, eng, prompts, max_news, **kw):
         ambient = obs.get_registry()
         for s in reg.spans():
             ambient.add_span(s)
-    return out, wall, snap["counters"], snap["gauges"]
+    return out, wall, snap["counters"]
 
 
 def run(fast: bool = True, smoke: bool = False):
@@ -87,7 +87,7 @@ def run(fast: bool = True, smoke: bool = False):
         # warmup pass populates the engine's jit caches (per-bucket
         # insertion, burst) so tokens_per_s is steady-state, not compile
         _serve(cls, eng, prompts, max_news, **kw)
-        out, wall, c, g = _serve(cls, eng, prompts, max_news, **kw)
+        out, wall, c = _serve(cls, eng, prompts, max_news, **kw)
         walls[name] = wall
         rows.append({
             "batcher": name,
@@ -97,7 +97,8 @@ def run(fast: bool = True, smoke: bool = False):
                                           0.0),
             "insertions": c.get("serve.insertions", 0.0),
             "slot_idle_steps": c.get("serve.slot_idle_steps", 0.0),
-            "slot_utilization": g.get("serve.slot_utilization", 0.0),
+            "slot_utilization": 1.0 - c.get("serve.slot_idle_steps", 0.0)
+            / c["serve.slot_steps"],
             "parity_ok": all(out.get(i) == ref[i] for i in ref),
         })
     wave_tokens = rows[0]["prefill_tokens"]

@@ -76,7 +76,7 @@ def mca_matmul(x: jax.Array, w: jax.Array, idx: jax.Array, inv_rp: jax.Array,
         out = _ref.ref_mca_matmul_fixed(x, w, idx, inv_rp, block)
         _emit_tel("mca_matmul", "device_sampled_blocks", 1, idx.shape[0])
         return out
-    with obs.trace("mca_matmul"):
+    with jax.named_scope("mca_matmul"):
         if devtel.enabled():
             out, tel = _mca_mod.mca_matmul_fixed(
                 x, w, idx, inv_rp, block=block, block_m=bm, block_f=bf,
@@ -137,7 +137,7 @@ def mca_matmul_ragged(x, w, r_tile, idx, inv_rp, *, block=128,
         _emit_tel("mca_matmul_ragged", "device_sampled_blocks",
                   1, jnp.sum(r_tile))
         return out
-    with obs.trace("mca_matmul_ragged"):
+    with jax.named_scope("mca_matmul_ragged"):
         if devtel.enabled():
             out, tel = _mca_mod.mca_matmul_ragged(
                 x, w, r_tile, idx, inv_rp, block=block, block_m=bm,
@@ -172,7 +172,7 @@ def kv_slot_update(cache: jax.Array, new: jax.Array, pos: jax.Array
         out = cache.at[jnp.arange(b), pos].set(new[:, 0])
         _emit_tel("kv_slot_update", "device_rows_written", 1, b)
         return out
-    with obs.trace("kv_slot_update"):
+    with jax.named_scope("kv_slot_update"):
         if devtel.enabled():
             out, tel = _cache_mod.kv_slot_update(
                 cache, new, pos, interpret=_interpret(), telemetry=True)
@@ -200,7 +200,7 @@ def flash_attention(q, k, v, *, scale, causal=True, block_q=128, block_k=128):
         out = _ref.ref_attention(q, k, v, scale=scale, causal=causal)
         _emit_tel("flash_attention", "device_tiles", 1, 0)
         return out
-    with obs.trace("flash_attention"):
+    with jax.named_scope("flash_attention"):
         if devtel.enabled():
             out, lse, tel = _flash_mod.flash_attention(
                 q, k, v, scale=scale, causal=causal, block_q=bq,
@@ -228,7 +228,7 @@ def attn_colmax(q, k, lse, *, scale, causal=True, block_q=128, block_k=128,
         cm = _ref.ref_colmax(q, k, lse, scale=scale, causal=causal)
         _emit_tel("attn_colmax", "device_tiles", 1, 0)
     else:
-        with obs.trace("attn_colmax"):
+        with jax.named_scope("attn_colmax"):
             if devtel.enabled():
                 cm, tel = _colmax_mod.attn_colmax(
                     q, k, lse, scale=scale, causal=causal, block_q=bq,

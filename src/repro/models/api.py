@@ -34,6 +34,7 @@ class Model:
 
 
 # ------------------------------------------------------------------ loss
+@jax.named_scope("logits")
 def chunked_xent(hidden, head, labels, cfg):
     """Sequence-chunked vocab-masked cross entropy.
 
@@ -74,6 +75,7 @@ def _head(params, cfg):
     return params["lm_head"]
 
 
+@jax.named_scope("logits")
 def _logits(params, cfg, hidden):
     logits = jnp.einsum("...d,dv->...v", hidden.astype(jnp.float32),
                         _head(params, cfg).astype(jnp.float32))
@@ -517,6 +519,7 @@ def _encdec_prefill(params, cfg, batch, max_len, mca_key=None):
     return {"layers": caches}, x, stats
 
 
+@jax.named_scope("attention")
 def _cross_decode(p, cfg, x, ck, cv):
     """One-query cross attention against cached encoder K/V."""
     b = x.shape[0]

@@ -386,6 +386,7 @@ def _acc_stats(acc, s):
     return out
 
 
+@jax.named_scope("attention")
 def gqa_attention(p, cfg, x, *, pos, mca_key=None, causal=None,
                   window=None, kv_x=None, return_kv=False, kv_valid=None):
     """Full-sequence (train / prefill) GQA attention with MCA on V/O.
@@ -474,8 +475,9 @@ def gqa_attention(p, cfg, x, *, pos, mca_key=None, causal=None,
                                     window=window, chunk=chunk,
                                     unroll=cfg.unroll_inner,
                                     kv_valid=kv_valid, q_valid=q_valid)
-        kv, s_v = mca_project(jax.random.fold_in(mca_key, 1), src, p["wv"],
-                              colmax, skv, cfg.mca, "v_proj")
+        with jax.named_scope("mca"):
+            kv, s_v = mca_project(jax.random.fold_in(mca_key, 1), src,
+                                  p["wv"], colmax, skv, cfg.mca, "v_proj")
         stats = _acc_stats(stats, s_v)
         v_cache = _split_heads(kv, hkv, dh)
         v = jnp.repeat(v_cache, g, axis=2) if repeat_kv else v_cache
@@ -506,8 +508,9 @@ def gqa_attention(p, cfg, x, *, pos, mca_key=None, causal=None,
 
     out = out.reshape(b, sq, cfg.n_heads * dh)
     if cfg.mca.active("o_proj") and mca_key is not None:
-        y, s_o = mca_project(jax.random.fold_in(mca_key, 2), out, p["wo"],
-                             rowmax, sq, cfg.mca, "o_proj")
+        with jax.named_scope("mca"):
+            y, s_o = mca_project(jax.random.fold_in(mca_key, 2), out,
+                                 p["wo"], rowmax, sq, cfg.mca, "o_proj")
         stats = _acc_stats(stats, s_o)
     else:
         y = out @ p["wo"]
@@ -574,6 +577,7 @@ def _decode_attn_chunked(qg, kc, vc, valid, scale, chunk):
     return out, a_max
 
 
+@jax.named_scope("attention")
 def gqa_decode(p, cfg, x, cache, *, t, pos_off=None):
     """Single-token decode. x: [B, 1, d]; t: scalar or [B] int32 position.
 
@@ -647,6 +651,7 @@ def init_mla(key, cfg):
     }
 
 
+@jax.named_scope("attention")
 def mla_attention(p, cfg, x, *, pos, mca_key=None, return_cache=False,
                   kv_valid=None):
     """MLA (latent) attention, full-sequence. MCA applies to the latent
@@ -690,8 +695,9 @@ def mla_attention(p, cfg, x, *, pos, mca_key=None, return_cache=False,
                                 window=0, chunk=chunk,
                                 unroll=cfg.unroll_inner, kv_valid=kv_valid,
                                 q_valid=kv_valid)
-        hv, s_v = mca_project(jax.random.fold_in(mca_key, 1), ckv, p["w_uv"],
-                              colmax, s, cfg.mca, "v_proj")
+        with jax.named_scope("mca"):
+            hv, s_v = mca_project(jax.random.fold_in(mca_key, 1), ckv,
+                                  p["w_uv"], colmax, s, cfg.mca, "v_proj")
         stats = _acc_stats(stats, s_v)
         v = _split_heads(hv, h, dv)
         out = chunked_av(qg, k, v, lse, scale=scale, causal=cfg.causal,
@@ -711,8 +717,9 @@ def mla_attention(p, cfg, x, *, pos, mca_key=None, return_cache=False,
 
     out = out.reshape(b, s, h * dv)
     if cfg.mca.active("o_proj") and mca_key is not None:
-        y, s_o = mca_project(jax.random.fold_in(mca_key, 2), out, p["wo"],
-                             rowmax, s, cfg.mca, "o_proj")
+        with jax.named_scope("mca"):
+            y, s_o = mca_project(jax.random.fold_in(mca_key, 2), out,
+                                 p["wo"], rowmax, s, cfg.mca, "o_proj")
         stats = _acc_stats(stats, s_o)
     else:
         y = out @ p["wo"]
@@ -728,6 +735,7 @@ def init_mla_cache(cfg, batch, max_len, dtype):
     }
 
 
+@jax.named_scope("attention")
 def mla_decode(p, cfg, x, cache, *, t, pos_off=None):
     """Absorbed-matrix MLA decode: scores/value read the latent cache
     directly; per-token cache cost is (kv_lora + rope) floats.

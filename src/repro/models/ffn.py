@@ -37,6 +37,7 @@ def init_ffn(key, cfg):
     return p
 
 
+@jax.named_scope("ffn")
 def ffn(p, cfg, x):
     if cfg.ffn_type == "swiglu":
         h = jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])
@@ -72,6 +73,7 @@ def moe_capacity(cfg, n_tokens: int) -> int:
     return max(8, ((c + 7) // 8) * 8)
 
 
+@jax.named_scope("ffn")
 def moe_ffn(p, cfg, x, *, mca_key=None):
     """x: [B, S, d] -> (y, aux_loss, stats).
 
@@ -181,6 +183,7 @@ def _moe_local(p, cfg, x, mca_key=None):
     return y.reshape(b, s, d), aux, stats
 
 
+@jax.named_scope("mca")
 def _mca_expert_matmul(key, cfg, xe, w_up, sorted_e, slot, gate_sorted,
                        cap, seq_len):
     """Per-expert Monte-Carlo up-projection driven by router gates.

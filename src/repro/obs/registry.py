@@ -51,7 +51,7 @@ class Counter:
 
 
 class Gauge:
-    """Last-write-wins scalar (flops reduction, slot occupancy)."""
+    """Last-write-wins scalar (flops reduction)."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -91,6 +91,27 @@ class Histogram:
                 # drop the oldest half; recency beats uniformity for perf
                 self._samples = self._samples[self._max // 2:]
             self._samples.append(v)
+
+    def between(self, start: int, stop: int) -> Optional[List[float]]:
+        """The observations whose running index (0 for the first ever
+        observed) lies in ``[start, stop)``, so a reader can take a
+        percentile over exactly a window's observations; None when the
+        reservoir no longer holds all of them."""
+        with self._lock:
+            first = self.count - len(self._samples)
+            if not 0 <= start <= stop <= self.count:
+                raise ValueError(f"[{start}, {stop}) is not within the "
+                                 f"{self.count} observations")
+            if start < first:
+                return None
+            return self._samples[start - first:stop - first]
+
+    def last(self, n: int) -> List[float]:
+        """The newest ``n`` observations, or every retained one where the
+        reservoir holds fewer."""
+        with self._lock:
+            return self._samples[len(self._samples) - min(
+                n, len(self._samples)):]
 
     @property
     def mean(self) -> float:

@@ -1,20 +1,31 @@
-"""Span timelines: request-scoped tracing exported as Chrome-trace JSON.
+"""Spans: one API for the profiler trace, the registry and its histograms.
 
-A *span* is a named host-side time interval (``time.perf_counter``
-stamps) with a category, a *track* (one timeline row — e.g.
-``serve.per_slot/req3`` follows one request end-to-end), and free-form
+``with obs.span(name, cat=..., track=..., hist=..., **args):`` brackets a
+host phase.  It does up to three things:
+
+* it always enters a ``jax.profiler.TraceAnnotation(name, **args)``, so
+  the phase lands in a profiler trace on the device trace's own clock,
+  with ``args`` as the event's stats (a few microseconds a span when no
+  profiler runs);
+* with ``hist="<histogram>"`` it always observes the body's seconds into
+  that histogram of the current registry;
+* while tracing is on (:func:`enable_tracing` / :func:`tracing`) it also
+  records a registry span for :func:`export_chrome_trace`.
+
+The span object keeps its ``perf_counter`` stamps (``t0``, ``t1``,
+``seconds``) for callers that derive more from them, and ``set(**args)``
+adds args known only at the end of the body.
+
+A registry span is a named host-side interval (``time.perf_counter``
+stamps) with a category, a *track* (one timeline row, e.g.
+``serve.per_slot/req3`` follows one request end to end), and free-form
 ``args``.  Spans are recorded into the current :class:`~.registry.Registry`
 (so ``obs.scoped()`` isolation applies) and exported with
 :func:`export_chrome_trace` as Chrome trace-event JSON that loads in
-``chrome://tracing`` or https://ui.perfetto.dev.
-
-Tracing is **off by default** and :func:`span` / :func:`record_span` are
-zero-overhead no-ops while disabled: no registry writes, no object
-allocation beyond the flag check, safe to call inside ``jit``-traced
-Python.  Enable with :func:`enable_tracing` (process-wide) or the
-:func:`tracing` context manager (tests, ``benchmarks.run --trace-out``).
-
-All spans share the ``perf_counter`` clock; a request chain looks like::
+``chrome://tracing`` or https://ui.perfetto.dev.  Tracing is **off by
+default**; :func:`record_span` and :func:`mark` are no-ops while it is.
+They draw spans after the fact, from stamps a caller already holds: a
+request's chain on its own track::
 
     queue → prefill → decode (one per burst) → finish
 
@@ -28,10 +39,11 @@ import json
 import time
 from typing import Any, Dict, Iterator, Mapping, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .registry import Registry, get_registry
 
 _enabled = False
-_NULL = contextlib.nullcontext()
 
 
 def enable_tracing(flag: bool = True) -> None:
@@ -98,52 +110,55 @@ def mark(
 
 
 class _Span:
-    """Context manager recording its body as one span on exit."""
+    """One host phase: a profiler annotation, optionally a histogram
+    observation and, while tracing is on, a registry span."""
 
-    __slots__ = ("name", "cat", "track", "args", "registry", "t0", "t1")
+    __slots__ = ("name", "cat", "track", "hist", "args", "t0", "t1",
+                 "_ann")
 
-    def __init__(self, name, cat, track, args, registry):
+    def __init__(self, name, cat, track, hist, args):
         self.name = name
         self.cat = cat
         self.track = track
+        self.hist = hist
         self.args = args
-        self.registry = registry
         self.t0 = 0.0
         self.t1 = 0.0
 
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def set(self, **args: Any) -> None:
+        """Add args known only inside the body (a step's loss)."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
     def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.t1 = time.perf_counter()
-        if exc_type is not None:
-            self.args = dict(self.args)
-            self.args["error"] = exc_type.__name__
-        record_span(
-            self.name,
-            self.t0,
-            self.t1,
-            cat=self.cat,
-            track=self.track,
-            args=self.args,
-            registry=self.registry,
-        )
+        self._ann.__exit__(exc_type, exc, tb)
+        if self.hist is not None:
+            get_registry().histogram(self.hist).observe(self.t1 - self.t0)
+        if _enabled:
+            if exc_type is not None:
+                self.args["error"] = exc_type.__name__
+            record_span(self.name, self.t0, self.t1, cat=self.cat,
+                        track=self.track, args=self.args)
 
 
-def span(
-    name: str,
-    cat: str = "",
-    track: str = "",
-    registry: Optional[Registry] = None,
-    **args: Any,
-):
-    """``with obs.span("prefill", cat="serve"): ...`` — records the body's
-    wall interval as a span. Returns a shared null context when tracing is
-    disabled (no allocation, no registry access)."""
-    if not _enabled:
-        return _NULL
-    return _Span(name, cat, track, args, registry)
+def span(name: str, cat: str = "", track: str = "",
+         hist: Optional[str] = None, **args: Any) -> _Span:
+    """``with obs.span("engine.admit", cat="serve", uid=7): ...`` — the
+    body as a profiler annotation (always), an observation of its seconds
+    into histogram ``hist`` (when given) and a registry span (while
+    tracing is on).  See the module docstring."""
+    return _Span(name, cat, track, hist, args)
 
 
 def export_chrome_trace(path: Optional[str], registry: Optional[Registry] = None) -> Dict[str, Any]:

@@ -51,16 +51,27 @@ Robustness (see ROADMAP.md § Robustness):
 Serving metrics land in the ``repro.obs`` registry: ``serve.prefill_seconds``,
 ``serve.decode_step_seconds``, ``serve.generated_tokens``,
 ``serve.prefill_tokens``, ``serve.insertions``,
-``serve.prefill_tokens_saved``, ``serve.slot_idle_steps``,
-``serve.flops_reduction``, ``serve.tier_occupancy.t{i}``, per-wave
-``serve.wave_seconds`` / ``serve.slot_utilization`` (live-slot occupancy:
-the fraction of slot-steps spent decoding real requests), admission
+``serve.prefill_tokens_saved``, ``serve.slot_steps`` and
+``serve.slot_idle_steps`` (live-slot occupancy over any window is
+1 - their deltas' ratio), ``serve.flops_reduction``,
+``serve.tier_occupancy.t{i}``, per-wave ``serve.wave_seconds``, admission
 counters ``serve.rejected.*`` and recovery counters ``resilience.serve.*``.
 Dummy padding slots in a partial wave are excluded from token and MCA
 FLOPs accounting.
+
+Every host phase runs under an ``obs.span`` (a profiler annotation on the
+device trace's clock): the engine's ``engine.prefill``,
+``engine.decode_loop``, ``engine.insert`` and ``engine.decode_burst``, the
+wave batcher's ``engine.wave``, and ``SlotBatcher.run``'s ``engine.slot_state_init``, ``engine.expire``,
+``engine.admit`` (enclosing ``engine.insert``) and ``engine.harvest``.
+``serve.batcher_host_seconds`` takes the batcher's own time in its phases,
+Engine calls left out; per request, ``serve.queue_seconds`` (submit to
+insertion start) and ``serve.inter_token_seconds`` (first token to finish
+over the tokens after the first).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import time
@@ -90,7 +101,12 @@ class Request:
     status: str = "queued"
     reason: Optional[str] = None          # set when rejected/failed
     submit_t: float = 0.0
-    submit_pc: float = 0.0                # perf_counter stamp (tracing)
+    # perf_counter stamps: submitted, insertion started, first token on
+    # the host, finished
+    submit_pc: float = 0.0
+    insert_pc: float = 0.0
+    first_pc: float = 0.0
+    finish_pc: float = 0.0
 
 
 @dataclasses.dataclass
@@ -178,13 +194,10 @@ class Engine:
                                     else make_prefill_into(None))
         self._kill = jax.jit(kill)
         self._bursts: Dict = {}          # (k, eos_id) -> jitted scan burst
-        # perf_counter windows of the most recent prefill / decode loop /
-        # insertion / burst — batchers read these to attribute per-request
-        # tracing spans without re-timing the jit calls
+        # perf_counter windows of generate()'s latest prefill and decode
+        # loop: the wave batcher draws its requests' spans from them
         self.last_prefill_t = (0.0, 0.0)
         self.last_decode_t = (0.0, 0.0)
-        self.last_insert_t = (0.0, 0.0)
-        self.last_burst_t = (0.0, 0.0)
 
     def _record_mca(self, stats, frac: float) -> None:
         """frac: fraction of batch rows that are real requests — dummy
@@ -230,16 +243,12 @@ class Engine:
             if (lens < s).any():
                 batch_in["pos_offset"] = jnp.asarray(s - lens, jnp.int32)
         prefill = self._prefill if mca else self._prefill_exact
-        t0p = time.perf_counter()
-        with obs.trace("engine.prefill"):
+        with obs.span("engine.prefill", cat="serve.engine", track="engine",
+                      hist="serve.prefill_seconds", batch=b, s=int(s),
+                      mca=bool(mca)) as sp:
             cache, logits, stats = prefill(self.params, batch_in)
             logits = jax.block_until_ready(logits)
-        t1p = time.perf_counter()
-        reg.histogram("serve.prefill_seconds").observe(t1p - t0p)
-        self.last_prefill_t = (t0p, t1p)
-        obs.record_span("prefill", t0p, t1p, cat="serve.engine",
-                        track="engine",
-                        args={"batch": b, "s": int(s), "mca": bool(mca)})
+        self.last_prefill_t = (sp.t0, sp.t1)
         logits = resilience.inject("serve.prefill", logits)
         if check_finite:
             resilience.check_finite(logits, "prefill logits")
@@ -255,8 +264,9 @@ class Engine:
         hist = reg.histogram("serve.decode_step_seconds")
         obs_every = self.decode_obs_every
         since = 0
-        t0d = t_last = time.perf_counter()
-        with obs.trace("engine.decode_loop"):
+        with obs.span("engine.decode_loop", cat="serve.engine",
+                      track="engine", steps=max_new - 1) as sp:
+            t_last = sp.t0
             resilience.inject("serve.decode")
             for _ in range(max_new - 1):
                 tok, cache, t_dev, bad = self._decode_step(
@@ -270,11 +280,8 @@ class Engine:
                     t_last, since = now, 0
             tok = jax.block_until_ready(tok)
         if since:
-            hist.observe((time.perf_counter() - t_last) / since)
-        t1d = time.perf_counter()
-        self.last_decode_t = (t0d, t1d)
-        obs.record_span("decode_loop", t0d, t1d, cat="serve.engine",
-                        track="engine", args={"steps": max_new - 1})
+            hist.observe((sp.t1 - t_last) / since)
+        self.last_decode_t = (sp.t0, sp.t1)
         if max_new > 1 and check_finite and bool(bad):
             raise resilience.NonFiniteError(
                 "non-finite values in decode logits")
@@ -321,11 +328,12 @@ class Engine:
                 f"prompt length {n} + max_new {max_new} overruns the "
                 f"KV cache (max_len={self.max_len})")
         s_pad = self.prefill_bucket(n, max_new)
-        padded = np.full((1, s_pad), self.pad_id, np.int32)
-        padded[0, s_pad - n:] = prompt
         fn = self._prefill_into if mca else self._prefill_into_exact
-        t0 = time.perf_counter()
-        with obs.trace("engine.insert"):
+        with obs.span("engine.insert", cat="serve.engine", track="engine",
+                      hist="serve.prefill_seconds", slot=slot, bucket=s_pad,
+                      mca=bool(mca)):
+            padded = np.full((1, s_pad), self.pad_id, np.int32)
+            padded[0, s_pad - n:] = prompt
             cache, tok, t, steps_left, logits, stats = fn(
                 self.params, jnp.asarray(padded),
                 jnp.asarray([s_pad - n], jnp.int32), state.cache,
@@ -333,23 +341,20 @@ class Engine:
                 jnp.asarray(slot, jnp.int32),
                 jnp.asarray(max_new - 1, jnp.int32))
             logits = jax.block_until_ready(logits)
-        t1 = time.perf_counter()
-        reg.histogram("serve.prefill_seconds").observe(t1 - t0)
-        self.last_insert_t = (t0, t1)
-        obs.record_span("insert", t0, t1, cat="serve.engine", track="engine",
-                        args={"slot": slot, "s_pad": s_pad, "mca": bool(mca)})
-        state = SlotState(cache, tok, t, steps_left)
-        reg.counter("serve.insertions").inc()
-        reg.counter("serve.prefill_tokens").inc(s_pad)
-        self._record_mca(stats, 1.0)
-        try:
-            logits_np = resilience.inject("serve.insert", np.asarray(logits))
-            resilience.check_finite(logits_np, "insert logits")
-        except Exception as e:
-            # the old state was donated into the jit call — hand callers
-            # the (consistent) new state so they can retry into the slot
-            e.slot_state = state
-            raise
+            state = SlotState(cache, tok, t, steps_left)
+            reg.counter("serve.insertions").inc()
+            reg.counter("serve.prefill_tokens").inc(s_pad)
+            self._record_mca(stats, 1.0)
+            try:
+                logits_np = resilience.inject("serve.insert",
+                                              np.asarray(logits))
+                resilience.check_finite(logits_np, "insert logits")
+            except Exception as e:
+                # the old state was donated into the jit call — hand
+                # callers the (consistent) new state so they can retry
+                # into the slot
+                e.slot_state = state
+                raise
         return state, logits_np[0, 0, :self.model.cfg.vocab_size], s_pad
 
     def _make_burst(self, k: int, eos_id: Optional[int]):
@@ -391,22 +396,22 @@ class Engine:
         host: per-row position, max-new countdown, EOS and finite flags
         are device-side inside one ``lax.scan``.  Returns
         ``(state, toks [B, k], bad [B], live_steps)`` — reading the
-        returned arrays is the single device→host sync per burst."""
+        returned arrays is the single device→host sync per burst.
+        Observes the burst's seconds over ``k`` into
+        ``serve.decode_step_seconds``."""
         fn = self._bursts.get((k, eos_id))
         if fn is None:
             fn = self._bursts[(k, eos_id)] = self._make_burst(k, eos_id)
-        t0 = time.perf_counter()
-        with obs.trace("engine.decode_burst"):
+        with obs.span("engine.decode_burst", cat="serve.engine",
+                      track="engine", k=k) as sp:
             tok, cache, t, steps_left, toks, bad, live = fn(
                 self.params, state.tok, state.cache, state.t,
                 state.steps_left)
-        state = SlotState(cache, tok, t, steps_left)
-        toks, bad, live = np.asarray(toks), np.asarray(bad), int(live)
-        t1 = time.perf_counter()
-        self.last_burst_t = (t0, t1)
-        obs.record_span("decode_burst", t0, t1, cat="serve.engine",
-                        track="engine", args={"k": k, "live_steps": live})
-        return state, toks, bad, live
+            toks, bad, live = np.asarray(toks), np.asarray(bad), int(live)
+            sp.set(live_steps=live)
+        obs.get_registry().histogram("serve.decode_step_seconds").observe(
+            sp.seconds / k)
+        return SlotState(cache, tok, t, steps_left), toks, bad, live
 
     def kill_slot(self, state: SlotState, slot: int) -> SlotState:
         """Zero a slot's decode budget (deadline expiry) on device."""
@@ -469,9 +474,11 @@ class ContinuousBatcher:
     def _finish(self, req: Request, status: str,
                 tokens: Optional[List[int]] = None) -> None:
         req.status = status
+        req.finish_pc = time.perf_counter()
         self.status[req.uid] = status
-        obs.mark("finish", cat=self.trace_cat, track=self._track(req),
-                 args={"status": status})
+        obs.record_span("finish", req.finish_pc, req.finish_pc,
+                        cat=self.trace_cat, track=self._track(req),
+                        args={"status": status})
         if tokens is not None:
             req.out = tokens
             self.done[req.uid] = tokens
@@ -561,30 +568,29 @@ class ContinuousBatcher:
                 for r in wave])
             lens = np.asarray([len(r.prompt) for r in wave], np.int32)
             max_new = max(r.max_new for r in wave)
-            t0 = time.perf_counter()
-            if obs.tracing_enabled():
-                for r in real:       # queued-until-wave-start per request
-                    obs.record_span("queue", r.submit_pc, t0,
-                                    cat=self.trace_cat, track=self._track(r))
-            try:
-                gen, degraded = self._run_wave(prompts, max_new, lens,
-                                               n_real)
-            except Exception as e:                         # noqa: BLE001
-                log.error("wave failed after retries: %s", e, exc_info=e)
-                for r in real:
-                    r.reason = str(e)
-                    self._finish(r, FAILED)
-                    reg.counter("resilience.serve.failed_requests").inc()
-                continue
-            t1 = time.perf_counter()
-            reg.histogram("serve.wave_seconds").observe(t1 - t0)
+            with obs.span("engine.wave", cat=self.trace_cat, track="waves",
+                          n_real=n_real) as sp:
+                if obs.tracing_enabled():
+                    for r in real:   # queued-until-wave-start per request
+                        obs.record_span("queue", r.submit_pc, sp.t0,
+                                        cat=self.trace_cat,
+                                        track=self._track(r))
+                try:
+                    gen, degraded = self._run_wave(prompts, max_new, lens,
+                                                   n_real)
+                except Exception as e:                     # noqa: BLE001
+                    log.error("wave failed after retries: %s", e,
+                              exc_info=e)
+                    for r in real:
+                        r.reason = str(e)
+                        self._finish(r, FAILED)
+                        reg.counter("resilience.serve.failed_requests").inc()
+                    continue
+                sp.set(degraded=degraded)
+            reg.histogram("serve.wave_seconds").observe(sp.seconds)
             if obs.tracing_enabled():
                 # attribute the wave's engine windows to every member so
                 # each request track shows its own prefill/decode spans
-                obs.record_span("wave", t0, t1, cat=self.trace_cat,
-                                track="waves",
-                                args={"n_real": n_real,
-                                      "degraded": degraded})
                 for r in real:
                     obs.record_span("prefill", *self.engine.last_prefill_t,
                                     cat=self.trace_cat,
@@ -594,12 +600,12 @@ class ContinuousBatcher:
                                     cat=self.trace_cat,
                                     track=self._track(r),
                                     args={"steps": max_new - 1})
-            # live-slot occupancy: fraction of slot-steps this wave spent
-            # decoding real requests (dummy slots and rows idling past
-            # their own max_new count as idle) — agrees with the
-            # SlotBatcher's serve.slot_idle_steps accounting
-            reg.gauge("serve.slot_utilization").set(
-                sum(min(r.max_new, max_new) for r in real) / (b * max_new))
+            # slot-steps this wave ran, and those not decoding a real
+            # request (dummy slots and rows idling past their own max_new)
+            # — the SlotBatcher's accounting
+            reg.counter("serve.slot_steps").inc(b * max_new)
+            reg.counter("serve.slot_idle_steps").inc(
+                b * max_new - sum(min(r.max_new, max_new) for r in real))
             reg.counter("serve.waves").inc()
             now = time.monotonic()
             for i, r in enumerate(real):
@@ -645,6 +651,37 @@ class SlotBatcher(ContinuousBatcher):
                          max_retries=max_retries, backoff_s=backoff_s)
         self.check_every = max(1, check_every)
         self.eos_id = eos_id
+        # wall seconds spent in Engine calls so far, and the latest call's
+        # perf_counter window (see _engine)
+        self._engine_s = 0.0
+        self._engine_t = (0.0, 0.0)
+
+    def _engine(self, fn, *args, **kw):
+        """Call ``fn``, an Engine method, timing the call: the batcher's
+        host-time histogram leaves Engine calls (and whatever runs inside
+        them) out, and request tracks draw their spans from the window."""
+        t0 = time.perf_counter()
+        try:
+            return fn(*args, **kw)
+        finally:
+            self._engine_t = (t0, time.perf_counter())
+            self._engine_s += self._engine_t[1] - t0
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **args):
+        """One host phase of ``run`` as a span; its time outside Engine
+        calls goes into ``serve.batcher_host_seconds``."""
+        e0 = self._engine_s
+        with obs.span(name, cat=self.trace_cat, track="batcher",
+                      **args) as sp:
+            yield sp
+        obs.get_registry().histogram("serve.batcher_host_seconds").observe(
+            sp.seconds - (self._engine_s - e0))
+
+    def _slot_state(self) -> SlotState:
+        with obs.span("engine.slot_state_init", cat=self.trace_cat,
+                      track="batcher"):
+            return self.engine.init_slot_state()
 
     def _insert(self, state: SlotState, slot: int, req: Request,
                 occupied_pads: List[int]):
@@ -652,6 +689,11 @@ class SlotBatcher(ContinuousBatcher):
         degradation ladder.  Returns ``(state, meta_or_None)``."""
         reg = obs.get_registry()
         eng = self.engine
+        req.insert_pc = time.perf_counter()
+        reg.histogram("serve.queue_seconds").observe(
+            req.insert_pc - req.submit_pc)
+        obs.record_span("queue", req.submit_pc, req.insert_pc,
+                        cat=self.trace_cat, track=self._track(req))
         last = None
         for attempt in range(self.max_retries + 1):
             use_mca = attempt == 0
@@ -662,8 +704,9 @@ class SlotBatcher(ContinuousBatcher):
                             exc_info=last)
                 time.sleep(self.backoff_s * (2 ** (attempt - 1)))
             try:
-                state, logits, s_pad = eng.prefill_into(
-                    req.prompt, state, slot, req.max_new, mca=use_mca)
+                state, logits, s_pad = self._engine(
+                    eng.prefill_into, req.prompt, state, slot, req.max_new,
+                    mca=use_mca)
             except ValueError:
                 raise    # deterministic (capacity): retrying can't help
             except Exception as e:                         # noqa: BLE001
@@ -673,10 +716,11 @@ class SlotBatcher(ContinuousBatcher):
                 last = e
                 continue
             first = int(logits.argmax())
+            req.first_pc = time.perf_counter()
             degraded = attempt > 0 and eng.mca_enabled
             if degraded:
                 reg.counter("resilience.serve.degraded_requests").inc()
-            obs.record_span("prefill", *eng.last_insert_t,
+            obs.record_span("prefill", req.insert_pc, req.first_pc,
                             cat=self.trace_cat, track=self._track(req),
                             args={"slot": slot, "s_pad": s_pad,
                                   "degraded": degraded})
@@ -693,59 +737,58 @@ class SlotBatcher(ContinuousBatcher):
         self._finish(req, FAILED)
         reg.counter("resilience.serve.failed_requests").inc()
         # the failed insertion may have armed the slot's decode budget
-        return eng.kill_slot(state, slot), None
+        return self._engine(eng.kill_slot, state, slot), None
 
     def _finish_slot(self, meta) -> None:
         req = meta["req"]
-        self._finish(req, DEGRADED if meta["degraded"] else OK,
-                     meta["out"][:req.max_new])
-        obs.get_registry().counter("serve.generated_tokens").inc(
-            len(meta["out"][:req.max_new]))
+        out = meta["out"][:req.max_new]
+        self._finish(req, DEGRADED if meta["degraded"] else OK, out)
+        reg = obs.get_registry()
+        reg.counter("serve.generated_tokens").inc(len(out))
+        if len(out) > 1:
+            reg.histogram("serve.inter_token_seconds").observe(
+                (req.finish_pc - req.first_pc) / (len(out) - 1))
 
     def run(self) -> Dict[int, List[int]]:
         reg = obs.get_registry()
         eng = self.engine
         b = eng.batch
-        state = eng.init_slot_state()
+        state = self._slot_state()
         slots: List[Optional[dict]] = [None] * b
         decode_failures = 0
-        cum_live = cum_total = 0
         while self.queue or any(s is not None for s in slots):
-            now = time.monotonic()
-            # drop expired queued work before it wastes an insertion
-            live_q = []
-            for r in self.queue:
-                if self._expired(r, now):
-                    self._finish(r, TIMEOUT)
-                    reg.counter("resilience.serve.timeouts").inc()
-                else:
-                    live_q.append(r)
-            self.queue = live_q
+            with self._phase("engine.expire", queued=len(self.queue)):
+                # drop expired queued work before it wastes an insertion
+                now = time.monotonic()
+                live_q = []
+                for r in self.queue:
+                    if self._expired(r, now):
+                        self._finish(r, TIMEOUT)
+                        reg.counter("resilience.serve.timeouts").inc()
+                    else:
+                        live_q.append(r)
+                self.queue = live_q
             # admit queued requests into free slots, one insertion each
             for slot in range(b):
                 if slots[slot] is not None or not self.queue:
                     continue
                 req = self.queue.pop(0)
-                obs.record_span("queue", req.submit_pc, time.perf_counter(),
-                                cat=self.trace_cat, track=self._track(req))
-                pads = [m["s_pad"] for m in slots if m is not None]
-                state, meta = self._insert(state, slot, req, pads)
-                if meta is None:
-                    continue
-                if meta["remaining"] <= 0:
-                    self._finish_slot(meta)
-                else:
-                    slots[slot] = meta
+                with self._phase("engine.admit", uid=req.uid, slot=slot):
+                    pads = [m["s_pad"] for m in slots if m is not None]
+                    state, meta = self._insert(state, slot, req, pads)
+                    if meta is not None and meta["remaining"] <= 0:
+                        self._finish_slot(meta)
+                    elif meta is not None:
+                        slots[slot] = meta
             if not any(s is not None for s in slots):
                 continue        # failures drained work; check queue again
             # K-step sync-free burst; K=1 under chaos so injected faults
             # surface with per-step granularity
             eff_k = 1 if resilience.active() else self.check_every
-            t0 = time.perf_counter()
             try:
                 resilience.inject("serve.decode")
-                state, toks, bad, live_steps = eng.decode_burst(
-                    state, eff_k, self.eos_id)
+                state, toks, bad, live_steps = self._engine(
+                    eng.decode_burst, state, eff_k, self.eos_id)
             except Exception as e:                         # noqa: BLE001
                 decode_failures += 1
                 reg.counter("resilience.serve.decode_retries").inc()
@@ -761,7 +804,7 @@ class SlotBatcher(ContinuousBatcher):
                         reg.counter(
                             "resilience.serve.failed_requests").inc()
                         slots[slot] = None
-                    state = eng.init_slot_state()
+                    state = self._slot_state()
                     decode_failures = 0
                 else:
                     log.warning("decode burst failed (%s); retry %d/%d",
@@ -770,48 +813,46 @@ class SlotBatcher(ContinuousBatcher):
                     time.sleep(self.backoff_s * (2 ** decode_failures))
                 continue
             decode_failures = 0
-            reg.histogram("serve.decode_step_seconds").observe(
-                (time.perf_counter() - t0) / eff_k)
-            if obs.tracing_enabled():
-                for s_meta in slots:      # one decode span per live slot
-                    if s_meta is not None:
-                        obs.record_span("decode", *eng.last_burst_t,
-                                        cat=self.trace_cat,
-                                        track=self._track(s_meta["req"]),
-                                        args={"k": eff_k})
-            reg.counter("serve.slot_idle_steps").inc(
-                eff_k * b - live_steps)
-            cum_live += live_steps
-            cum_total += eff_k * b
-            reg.gauge("serve.slot_utilization").set(cum_live / cum_total)
-            now = time.monotonic()
-            for slot in range(b):
-                meta = slots[slot]
-                if meta is None:
-                    continue
-                req = meta["req"]
-                take = min(meta["remaining"], eff_k)
-                got = toks[slot, :take].tolist()
-                if self.eos_id is not None and self.eos_id in got:
-                    got = got[:got.index(self.eos_id) + 1]
-                meta["out"].extend(got)
-                meta["remaining"] -= len(got)
-                if bool(bad[slot]):
-                    state, meta = self._restart_exact(state, slot, req)
-                    if meta is not None and meta["remaining"] <= 0:
+            burst_t = self._engine_t
+            with self._phase("engine.harvest", live_steps=live_steps):
+                if obs.tracing_enabled():
+                    for s_meta in slots:  # one decode span per live slot
+                        if s_meta is not None:
+                            obs.record_span(
+                                "decode", *burst_t, cat=self.trace_cat,
+                                track=self._track(s_meta["req"]),
+                                args={"k": eff_k})
+                reg.counter("serve.slot_steps").inc(eff_k * b)
+                reg.counter("serve.slot_idle_steps").inc(
+                    eff_k * b - live_steps)
+                now = time.monotonic()
+                for slot in range(b):
+                    meta = slots[slot]
+                    if meta is None:
+                        continue
+                    req = meta["req"]
+                    take = min(meta["remaining"], eff_k)
+                    got = toks[slot, :take].tolist()
+                    if self.eos_id is not None and self.eos_id in got:
+                        got = got[:got.index(self.eos_id) + 1]
+                    meta["out"].extend(got)
+                    meta["remaining"] -= len(got)
+                    if bool(bad[slot]):
+                        state, meta = self._restart_exact(state, slot, req)
+                        if meta is not None and meta["remaining"] <= 0:
+                            self._finish_slot(meta)
+                            meta = None
+                        slots[slot] = meta
+                    elif self._expired(req, now):
+                        self._finish(req, TIMEOUT)
+                        reg.counter("resilience.serve.timeouts").inc()
+                        state = self._engine(eng.kill_slot, state, slot)
+                        slots[slot] = None
+                    elif (meta["remaining"] <= 0
+                          or (self.eos_id is not None
+                              and got and got[-1] == self.eos_id)):
                         self._finish_slot(meta)
-                        meta = None
-                    slots[slot] = meta
-                elif self._expired(req, now):
-                    self._finish(req, TIMEOUT)
-                    reg.counter("resilience.serve.timeouts").inc()
-                    state = eng.kill_slot(state, slot)
-                    slots[slot] = None
-                elif (meta["remaining"] <= 0
-                      or (self.eos_id is not None
-                          and got and got[-1] == self.eos_id)):
-                    self._finish_slot(meta)
-                    slots[slot] = None
+                        slots[slot] = None
         return self.done
 
     def _restart_exact(self, state: SlotState, slot: int, req: Request):
@@ -824,20 +865,22 @@ class SlotBatcher(ContinuousBatcher):
         log.warning("slot %d produced non-finite logits; restarting with "
                     "exact attention", slot)
         try:
-            state, logits, s_pad = eng.prefill_into(
-                req.prompt, state, slot, req.max_new, mca=False)
+            state, logits, s_pad = self._engine(
+                eng.prefill_into, req.prompt, state, slot, req.max_new,
+                mca=False)
         except Exception as e:                             # noqa: BLE001
             state = getattr(e, "slot_state", state)
             req.reason = str(e)
             self._finish(req, FAILED)
             reg.counter("resilience.serve.failed_requests").inc()
-            return eng.kill_slot(state, slot), None
+            return self._engine(eng.kill_slot, state, slot), None
         first = int(logits.argmax())
+        req.first_pc = time.perf_counter()    # the output starts over
         degraded = eng.mca_enabled
         if degraded:
             reg.counter("resilience.serve.degraded_requests").inc()
-        obs.record_span("prefill", *eng.last_insert_t, cat=self.trace_cat,
-                        track=self._track(req),
+        obs.record_span("prefill", self._engine_t[0], req.first_pc,
+                        cat=self.trace_cat, track=self._track(req),
                         args={"slot": slot, "restart": True,
                               "degraded": degraded})
         done = (self.eos_id is not None

@@ -163,19 +163,17 @@ class Trainer:
                 self.start_step = step
                 log.info("restored checkpoint at step %d", step)
 
+    @staticmethod
+    def _span(name: str, step: int, hist: Optional[str] = None):
+        return obs.span(name, cat="train", track="trainer", hist=hist,
+                        step=step)
+
     def _record_step(self, step: int, loss: float, dt: float, metrics,
                      status: str = "ok"):
         """Per-step MCA stats -> obs registry (+ optional JSONL record)."""
         reg = obs.get_registry()
         reg.counter("train.steps").inc()
         reg.histogram("train.step_seconds").observe(dt)
-        span = getattr(self, "_last_step_span", None)
-        if span is not None:
-            obs.record_span("train.step", span[0], span[1], cat="train",
-                            track="trainer",
-                            args={"step": step, "status": status,
-                                  "loss": loss if math.isfinite(loss)
-                                  else str(loss)})
         record: Dict[str, Any] = {"step": step, "loss": loss, "dt": dt,
                                   "status": status}
         if "mca_exact_flops" in metrics:
@@ -244,61 +242,81 @@ class Trainer:
                           step)
 
     def run(self) -> Dict[str, Any]:
+        """Train to ``cfg.total_steps``.  Each step's host phases run under
+        spans: ``trainer.data``, ``trainer.to_device``, ``trainer.step``
+        over ``trainer.dispatch`` (the ``train_step`` call) and
+        ``trainer.wait`` (the loss read back), ``trainer.checks``,
+        ``trainer.record``.  ``train.dispatch_seconds`` takes the dispatch,
+        ``train.host_seconds`` the step's wall time less dispatch and
+        wait."""
         reg = obs.get_registry()
         step = self.start_step
         t_start = time.time()
         while step < self.cfg.total_steps:
-            batch = self.data.batch(step)
-            batch = jax.tree.map(jax.numpy.asarray, batch)
-            self.watchdog.arm(step)
-            t0 = time.time()
-            tp0 = time.perf_counter()
-            resilience.inject("train.step")
-            with obs.trace("trainer.step"):
-                new_params, new_opt, metrics = self.train_step(
-                    self.params, self.opt_state, batch)
-                loss = float(metrics["total_loss"])   # sync point
-            tp1 = time.perf_counter()
-            loss = resilience.inject("train.loss", loss)
-            if loss is None:
-                loss = float("nan")
-            self.watchdog.disarm()
-            dt = time.time() - t0
-            self._last_step_span = (tp0, tp1)
-            if self._step_is_bad(loss, metrics):
-                self._bad_streak += 1
-                reg.counter("train.skipped_steps").inc()
-                log.warning("step %d: non-finite loss/grads (loss=%s) — "
-                            "skipping update (%d consecutive)",
-                            step + 1, loss, self._bad_streak)
-                if self._bad_streak >= self.cfg.max_bad_steps:
-                    if self.rollbacks >= self.cfg.max_rollbacks:
-                        raise TrainingDivergedError(
-                            f"step {step + 1}: {self._bad_streak} "
-                            f"consecutive non-finite steps after "
-                            f"{self.rollbacks} rollbacks — deterministic "
-                            f"replay would reproduce the same divergence; "
-                            f"aborting instead of livelocking")
-                    step = self._rollback(step + 1)
+            n = step + 1
+            with self._span("trainer.data", n) as data:
+                batch = self.data.batch(step)
+            with self._span("trainer.to_device", n):
+                batch = jax.tree.map(jax.numpy.asarray, batch)
+            with self._span("trainer.step", n) as sp:
+                self.watchdog.arm(step)
+                resilience.inject("train.step")
+                with self._span("trainer.dispatch", n,
+                                hist="train.dispatch_seconds") as dispatch:
+                    new_params, new_opt, metrics = self.train_step(
+                        self.params, self.opt_state, batch)
+                with self._span("trainer.wait", n) as wait:
+                    loss = float(metrics["total_loss"])   # sync point
+                loss = resilience.inject("train.loss", loss)
+                if loss is None:
+                    loss = float("nan")
+                self.watchdog.disarm()
+                sp.set(loss=loss if math.isfinite(loss) else str(loss))
+            dt = sp.seconds
+            with self._span("trainer.checks", n) as checks:
+                bad = self._step_is_bad(loss, metrics)
+                checks.set(bad=bad)
+                if bad:
+                    self._bad_streak += 1
+                    reg.counter("train.skipped_steps").inc()
+                    log.warning("step %d: non-finite loss/grads (loss=%s) "
+                                "— skipping update (%d consecutive)",
+                                n, loss, self._bad_streak)
+                    if self._bad_streak >= self.cfg.max_bad_steps:
+                        if self.rollbacks >= self.cfg.max_rollbacks:
+                            raise TrainingDivergedError(
+                                f"step {n}: {self._bad_streak} "
+                                f"consecutive non-finite steps after "
+                                f"{self.rollbacks} rollbacks — "
+                                f"deterministic replay would reproduce "
+                                f"the same divergence; aborting instead "
+                                f"of livelocking")
+                        step = self._rollback(n)
+                        self._bad_streak = 0
+                        continue
+                    # skip: keep pre-step params/opt, advance past the
+                    # batch (non-donating train_step — enforced at init)
+                else:
                     self._bad_streak = 0
-                    continue
-                # skip: keep pre-step params/opt, advance past the batch
-                # (non-donating train_step — enforced at init)
-                step += 1
-                self.history.append(self._record_step(
-                    step, loss, dt, metrics, status="skipped"))
-                continue
-            self._bad_streak = 0
-            self.params, self.opt_state = new_params, new_opt
+                    self.params, self.opt_state = new_params, new_opt
             step += 1
-            record = self._record_step(step, loss, dt, metrics)
-            self.history.append(record)
-            if step % self.cfg.log_every == 0 or step == 1:
-                fr = record.get("flops_reduction")
-                log.info("step %d loss %.4f (%.2fs/step)%s", step, loss, dt,
-                         "" if fr is None else f" flops_reduction {fr:.2f}x")
-            if self.checkpointer and step % self.cfg.ckpt_every == 0:
-                self._save(step)
+            with self._span("trainer.record", n) as rec:
+                record = self._record_step(
+                    step, loss, dt, metrics,
+                    status="skipped" if bad else "ok")
+                self.history.append(record)
+                if not bad and (step % self.cfg.log_every == 0
+                                or step == 1):
+                    fr = record.get("flops_reduction")
+                    log.info("step %d loss %.4f (%.2fs/step)%s", step, loss,
+                             dt, "" if fr is None
+                             else f" flops_reduction {fr:.2f}x")
+                if (not bad and self.checkpointer
+                        and step % self.cfg.ckpt_every == 0):
+                    self._save(step)
+            # the step's host time: all of it but the dispatch and the wait
+            reg.histogram("train.host_seconds").observe(
+                rec.t1 - data.t0 - dispatch.seconds - wait.seconds)
         if self.checkpointer:
             self._save(self.cfg.total_steps)
             try:

@@ -110,16 +110,105 @@ class TestSink:
         assert recs[0]["counters"]["x"] == 3.0
 
 
+def _profile(tmp_path, body):
+    """Run ``body`` under the JAX profiler on the CPU; returns
+    {event name: [(start_ns, end_ns, stats)]} of every host event."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                events.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+    return events
+
+
 class TestTrace:
     def test_trace_and_annotate_are_noop_safe(self):
-        with obs.trace("unit.test"):
-            x = 1 + 1
+        """``obs.span`` is the one span API (``obs.trace`` and
+        ``obs.annotate`` are gone): with no profiler running and tracing
+        off, the body runs, the stamps are ordered and nothing is
+        recorded."""
+        assert not hasattr(obs, "trace") and not hasattr(obs, "annotate")
+        with obs.scoped() as reg:
+            with obs.span("unit.test", cat="c", k=1) as sp:
+                x = 1 + 1
+        assert x == 2
+        assert sp.t1 >= sp.t0 and sp.seconds == sp.t1 - sp.t0
+        assert reg.spans() == []
+        assert reg.snapshot()["histograms"] == {}
 
-        @obs.annotate("unit.fn")
-        def fn(a):
-            return a * 2
+    def test_span_lands_in_profiler_trace(self, tmp_path):
+        """Name, args as event stats and nesting, read back through
+        ProfileData on the CPU."""
+        def body():
+            with obs.span("unit.outer", step=3):
+                with obs.span("unit.inner", uid=7, slot=2) as sp:
+                    sp.set(loss=1.5)
 
-        assert x == 2 and fn(3) == 6
+        events = _profile(tmp_path, body)
+        [(o0, o1, o_stats)] = events["unit.outer"]
+        [(i0, i1, i_stats)] = events["unit.inner"]
+        assert o_stats == {"step": 3}
+        assert i_stats == {"uid": 7, "slot": 2, "loss": 1.5}
+        assert o0 <= i0 <= i1 <= o1
+
+    def test_span_writes_annotation_with_tracing_off(self, tmp_path):
+        assert not obs.tracing_enabled()
+        with obs.scoped() as reg:
+            def body():
+                with obs.span("unit.untraced", cat="c"):
+                    pass
+
+            events = _profile(tmp_path, body)
+        assert len(events["unit.untraced"]) == 1
+        assert reg.spans() == []
+
+    def test_span_hist_observes_body_seconds(self):
+        import time
+
+        with obs.scoped() as reg:
+            with obs.span("unit.timed", hist="unit.seconds") as sp:
+                time.sleep(0.01)
+            h = reg.histogram("unit.seconds")
+        assert h.count == 1
+        assert h.total == sp.seconds >= 0.01
+        assert reg.spans() == []
+
+
+class TestHistogramWindow:
+    def test_between_and_last_at_the_edges(self):
+        h = obs.Histogram(max_samples=4)
+        for v in range(3):
+            h.observe(float(v))
+        assert h.between(0, 3) == [0.0, 1.0, 2.0]
+        assert h.between(1, 2) == [1.0]
+        assert h.between(0, 0) == [] and h.between(3, 3) == []
+        for bad in ((2, 1), (0, 4), (-1, 0)):
+            with pytest.raises(ValueError):
+                h.between(*bad)
+        assert h.last(2) == [1.0, 2.0] and h.last(0) == []
+
+    def test_between_is_none_after_the_reservoir_halves(self):
+        h = obs.Histogram(max_samples=4)
+        for v in range(5):               # the 5th halves [0..3] to [2, 3]
+            h.observe(float(v))
+        assert h.count == 5
+        assert h.between(0, 5) is None and h.between(1, 3) is None
+        assert h.between(2, 5) == [2.0, 3.0, 4.0]
+        assert h.between(4, 5) == [4.0] and h.between(5, 5) == []
+        assert h.last(2) == [3.0, 4.0]
+        assert h.last(10) == [2.0, 3.0, 4.0]     # all it holds
 
 
 class TestTrainerIntegration:
@@ -167,20 +256,54 @@ class TestTrainerIntegration:
         # trainer history mirrors the records
         assert res["history"][-1]["flops_reduction"] > 1.0
 
+    def test_trainer_observes_phase_histograms(self):
+        """Once per step: the dispatch's seconds, and the step's host
+        time (its wall time less the dispatch and the loss wait)."""
+        import jax
+        from repro.configs import get_config
+        from repro.data import SyntheticLM
+        from repro.models import build_model, reduced
+        from repro.optim import adamw
+        from repro.train.step import make_train_step
+        from repro.train.trainer import Trainer, TrainerConfig
+
+        cfg = reduced(get_config("starcoder2-3b"), n_layers=1, d_model=32,
+                      n_heads=2, n_kv_heads=1, d_head=16, d_ff=64,
+                      vocab_size=64)
+        model = build_model(cfg)
+        data = SyntheticLM(cfg.vocab_size, 8, 2, seed=0)
+        opt = adamw.AdamWConfig(lr=1e-3)
+        step = jax.jit(make_train_step(model, opt))
+        with obs.scoped() as reg:
+            Trainer(model, opt, data, step,
+                    TrainerConfig(total_steps=3, log_every=100)).run()
+            snap = reg.snapshot()
+        h = snap["histograms"]
+        assert h["train.dispatch_seconds"]["count"] == 3
+        assert h["train.host_seconds"]["count"] == 3
+        assert h["train.host_seconds"]["min"] >= 0.0
+        assert h["train.dispatch_seconds"]["sum"] <= \
+            h["train.step_seconds"]["sum"]
+
 
 class TestTracing:
     def test_disabled_is_complete_noop(self):
-        """Satellite: with tracing off, span machinery must not touch the
-        registry, must not allocate per call, and must not raise."""
+        """With tracing off, span machinery records no registry span and
+        does not raise; a span's ``hist`` is the one registry write that
+        does not wait for tracing."""
         assert not obs.tracing_enabled()
         with obs.scoped() as reg:
             with obs.span("x", cat="c", extra=1):
                 pass
+            with obs.span("w", cat="c", hist="h.seconds"):
+                pass
             obs.record_span("y", 0.0, 1.0, cat="c")
             obs.mark("z", cat="c")
         assert reg.spans() == []
-        # disabled span() hands back one shared null context
-        assert obs.span("a") is obs.span("b")
+        snap = reg.snapshot()
+        assert list(snap["histograms"]) == ["h.seconds"]
+        assert snap["histograms"]["h.seconds"]["count"] == 1
+        assert snap["counters"] == {} and snap["gauges"] == {}
 
     def test_noop_inside_jit(self):
         """Span calls inside jit-traced Python: no exceptions, no registry
@@ -336,6 +459,34 @@ class TestRequestChains:
         xs = [e for e in trace["traceEvents"] if e["ph"] == "X"]
         assert len(xs) == len(reg.spans())
         assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in xs)
+
+    def test_slot_batcher_observes_request_histograms(self, serve_setup):
+        """Tracing off: ``serve.queue_seconds`` once per insertion,
+        ``serve.inter_token_seconds`` once per finished request of two or
+        more tokens, the batcher's host time once per phase run."""
+        from repro.serve import Engine, Request, SlotBatcher
+
+        cfg, model, params = serve_setup
+        eng = Engine(model, params, batch_size=2, max_len=64)
+        rng = np.random.default_rng(13)
+        reqs = [Request(uid=i, max_new=m, prompt=rng.integers(
+            1, cfg.vocab_size, size=n).astype(np.int32))
+            for i, (n, m) in enumerate(((4, 1), (9, 4), (6, 3)))]
+        with obs.scoped() as reg:
+            b = SlotBatcher(eng, check_every=2)
+            for r in reqs:
+                assert b.submit(r) == "queued"
+            b.run()
+            snap = reg.snapshot()
+        h = snap["histograms"]
+        assert h["serve.queue_seconds"]["count"] == 3
+        assert h["serve.inter_token_seconds"]["count"] == 2  # not max_new 1
+        assert h["serve.batcher_host_seconds"]["count"] > 0
+        assert h["serve.batcher_host_seconds"]["min"] >= 0.0
+        for r in reqs:
+            assert r.status == "ok"
+            assert r.submit_pc <= r.insert_pc <= r.first_pc <= r.finish_pc
+        assert reg.spans() == []
 
     def test_no_spans_when_tracing_disabled(self, serve_setup):
         """Serving with tracing off must leave the registry span-free."""

@@ -491,7 +491,8 @@ class TestServeAdmission:
             b.run()                      # 1 real request, 1 dummy slot
             snap = reg.snapshot()
         assert snap["counters"]["serve.generated_tokens"] == 4
-        assert snap["gauges"]["serve.slot_utilization"] == 0.5
+        c = snap["counters"]
+        assert 1 - c["serve.slot_idle_steps"] / c["serve.slot_steps"] == 0.5
 
 
 class TestServeDegradation:

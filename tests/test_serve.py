@@ -115,7 +115,10 @@ def test_engine_records_obs_metrics(engine_setup):
     assert snap["counters"]["serve.generated_tokens"] == 3 * 4
     assert snap["histograms"]["serve.prefill_seconds"]["count"] == 2
     assert snap["histograms"]["serve.wave_seconds"]["count"] == 2
-    assert snap["gauges"]["serve.slot_utilization"] == 0.5   # last wave 1/2
+    # wave 1: 2 x 4 slot-steps all live; wave 2: 1 real request of 4 steps
+    # beside a dummy slot -> 16 slot-steps, 4 idle
+    assert snap["counters"]["serve.slot_steps"] == 16
+    assert snap["counters"]["serve.slot_idle_steps"] == 4
     # MCA disabled: stats still flow, reduction is exactly 1x
     assert snap["gauges"]["serve.flops_reduction"] == 1.0
 
@@ -199,13 +202,12 @@ def test_slot_batcher_metrics(engine_setup):
     # slot is still occupied, so >= one occupied pad is "saved" prefill
     assert c["serve.prefill_tokens"] == 3 * 8
     assert c["serve.prefill_tokens_saved"] >= 8
-    util = snap["gauges"]["serve.slot_utilization"]
     idle = c.get("serve.slot_idle_steps", 0)
+    util = 1 - idle / c["serve.slot_steps"]
     assert 0 < util <= 1
-    # utilization + idle fraction account for every slot-step burst
+    # the slot-steps counted are every burst's: k steps of every slot
     hist = snap["histograms"]["serve.decode_step_seconds"]
-    total = hist["count"] * 4 * eng.batch
-    assert abs(util - (total - idle) / total) < 1e-9
+    assert c["serve.slot_steps"] == hist["count"] * 4 * eng.batch
 
 
 def test_slot_batcher_eos_and_deadline(engine_setup):
