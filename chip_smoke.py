@@ -129,7 +129,8 @@ def scatter_kv_writes():
 
 def kv_write_check(seed: int) -> None:
     """The KV write kernel vs the scatter it replaces, bit for bit, on a
-    served layer's cache shape, over an 8-step scan like a decode burst."""
+    2-layer stack of a served layer's cache shape, over an 8-step scan
+    like a decode burst."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -137,10 +138,12 @@ def kv_write_check(seed: int) -> None:
     from repro import obs
     from repro.kernels import kv_slot_update
 
-    b, s, heads, d_head, steps = 4, 1024, 2, 128, 8
+    layers, b, s, heads, d_head, steps = 2, 4, 1024, 2, 128, 8
     kc, kn = jax.random.split(jax.random.PRNGKey(seed))
-    cache = jax.random.normal(kc, (b, s, heads, d_head), jnp.bfloat16)
-    new = jax.random.normal(kn, (steps, b, 1, heads, d_head), jnp.bfloat16)
+    cache = jax.random.normal(kc, (layers, b, s, heads, d_head),
+                              jnp.bfloat16)
+    new = jax.random.normal(kn, (steps, layers, b, 1, heads, d_head),
+                            jnp.bfloat16)
     pos = jnp.asarray([0, 15, 511, s - steps], jnp.int32)
 
     def run():                      # a fresh trace per call

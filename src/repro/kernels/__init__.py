@@ -6,9 +6,10 @@ projection; kernels here are its TPU-native counterparts (see DESIGN.md):
   flash_attention online-softmax fwd producing LSE (the colmax enabler)
   attn_colmax     Eq.9 r-driver: max_i A[i,j] in O(n) memory
   kv_slot_update  per-row KV-cache write (per-slot continuous batching)
+  decode_attention one-token attention over a layer-stacked KV cache
 """
-from .ops import (attn_colmax, flash_attention, kv_slot_update,
-                  mca_matmul, mca_matmul_ragged)
+from .ops import (attn_colmax, decode_attention, flash_attention,
+                  kv_slot_update, mca_matmul, mca_matmul_ragged)
 
-__all__ = ["attn_colmax", "flash_attention", "kv_slot_update",
-           "mca_matmul", "mca_matmul_ragged"]
+__all__ = ["attn_colmax", "decode_attention", "flash_attention",
+           "kv_slot_update", "mca_matmul", "mca_matmul_ragged"]

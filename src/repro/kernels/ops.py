@@ -19,7 +19,8 @@ Two layers of accounting, deliberately distinct (see ROADMAP § Observability):
   per-op work count (``device_sampled_blocks`` for the MCA matmuls —
   sampled block contributions accumulated in-kernel, so the ragged
   kernel's skipped samples are excluded; ``device_tiles`` for
-  flash/colmax score tiles; ``device_rows_written`` for the KV update).
+  flash/colmax score tiles; ``device_rows_written`` for the KV update;
+  ``device_rows_read`` for decode attention, analytic on both paths).
   On the kernel path the counts come from an in-kernel telemetry buffer
   (kernels/telemetry.py); on the fallback path the wrapper emits the
   analytically equivalent values, so both paths report launches the same
@@ -36,6 +37,7 @@ from repro.obs import devtel
 
 from . import attn_colmax as _colmax_mod
 from . import cache_update as _cache_mod
+from . import decode_attention as _decode_mod
 from . import flash_attention as _flash_mod
 from . import mca_matmul as _mca_mod
 from . import ref as _ref
@@ -152,25 +154,29 @@ def mca_matmul_ragged(x, w, r_tile, idx, inv_rp, *, block=128,
 
 def kv_slot_update(cache: jax.Array, new: jax.Array, pos: jax.Array
                    ) -> jax.Array:
-    """Per-row KV-cache write: ``cache[b, pos[b]] = new[b, 0]``.
+    """Per-row KV-cache write into every layer of a layer-stacked cache:
+    ``cache[l, b, pos[b]] = new[l, b, 0]``.
 
-    cache: [B, S, ...]; new: [B, 1, ...] (same trailing dims); pos: [B]
-    int32.  The Pallas kernel DMAs each new row to ``cache[b, pos[b]]``
-    (only the B touched rows are written, in place through
-    ``input_output_aliases``, with no reshape of the cache); when a row
-    is not whole tiles of the cache's layout (``cache_update.row_dma_ok``)
-    the XLA scatter fallback runs instead.
+    cache: [L, B, S, ...]; new: [L, B, 1, ...] (same trailing dims), the
+    rows a decode step's layer scan produced; pos: [B] int32.  The Pallas
+    kernel DMAs each new row to its place in the stack (only the L * B
+    touched rows are written, in place through ``input_output_aliases``,
+    with no reshape of the cache); when a row is not whole tiles of one
+    layer's layout (``cache_update.row_dma_ok``) the XLA scatter fallback
+    writes the same rows of the same buffer.
 
-    Device telemetry: ``device_rows_written == B`` per execution on both
-    paths — a K-step decode burst therefore shows K launches where the
-    dispatch counter shows one traced call site.
+    Device telemetry: ``device_rows_written == L * B`` per execution on
+    both paths — a K-step decode burst therefore shows K launches where
+    the dispatch counter shows one traced call site.
     """
-    b = cache.shape[0]
-    use_kernel = _cache_mod.row_dma_ok(cache.shape, cache.dtype)
+    b = cache.shape[1]
+    use_kernel = _cache_mod.row_dma_ok(cache.shape[1:], cache.dtype)
     _count("kv_slot_update", use_kernel)
     if not use_kernel:
-        out = cache.at[jnp.arange(b), pos].set(new[:, 0])
-        _emit_tel("kv_slot_update", "device_rows_written", 1, b)
+        out = cache.at[:, jnp.arange(b), pos].set(
+            new[:, :, 0].astype(cache.dtype))
+        _emit_tel("kv_slot_update", "device_rows_written", 1,
+                  cache.shape[0] * b)
         return out
     with jax.named_scope("kv_slot_update"):
         if devtel.enabled():
@@ -182,6 +188,39 @@ def kv_slot_update(cache: jax.Array, new: jax.Array, pos: jax.Array
             out = _cache_mod.kv_slot_update(cache, new, pos,
                                             interpret=_interpret())
     return out
+
+
+def decode_attention(q, k, v, valid, s1, v1, layer, *, scale, hkv):
+    """One-token attention over layer ``layer`` of a layer-stacked cache
+    whose kv heads are folded into its rows, seeded with the current
+    token's own score and value (see ``kernels.decode_attention`` for the
+    shapes).  Returns (out [B, R, dh], l [B, R, 1]).
+
+    The Pallas kernel reads the layer's K/V blocks straight from the
+    stack in HBM; where no block fits (``decode_attention.pick_blocks``:
+    a head dim that is not whole lanes) the jnp fallback runs the same
+    online softmax over dynamic slices of the stack, in chunks of 1024
+    slots once a row holds 8192 or more (so the f32 scores of a long
+    context are never all live).
+
+    Device telemetry: ``device_rows_read`` counts the B * N cache rows
+    one execution reads, on both paths (analytic).
+    """
+    b, _, dh = q.shape
+    n = k.shape[2]
+    blocks = _decode_mod.pick_blocks(b, n, dh, k.dtype.itemsize)
+    _count("decode_attention", blocks is not None)
+    _emit_tel("decode_attention", "device_rows_read", 1, b * n)
+    if blocks is None:
+        slots = n // hkv
+        chunk = (1024 * hkv if slots >= 8192 and slots % 1024 == 0
+                 else n)
+        return _ref.ref_decode_attention(q, k, v, valid, s1, v1, layer,
+                                         scale=scale, hkv=hkv, chunk=chunk)
+    with jax.named_scope("decode_attention"):
+        return _decode_mod.decode_attention(
+            q, k, v, valid, s1, v1, layer, scale=scale, hkv=hkv,
+            block_b=blocks[0], block_n=blocks[1], interpret=_interpret())
 
 
 def flash_attention(q, k, v, *, scale, causal=True, block_q=128, block_k=128):

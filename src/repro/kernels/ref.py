@@ -68,3 +68,38 @@ def ref_colmax(q, k, lse, *, scale, causal=True):
         mask = jnp.tril(jnp.ones((sq, skv), bool), k=skv - sq)
         a = jnp.where(mask[None, None], a, 0.0)
     return jnp.max(a, axis=2)        # over queries -> [B,Hq,Skv]
+
+
+def ref_decode_attention(q, k, v, valid, s1, v1, layer, *, scale, hkv,
+                         chunk):
+    """Oracle (and fallback) for decode_attention: the same online softmax
+    over ``chunk``-row slices of layer ``layer`` of the stack, read with
+    dynamic slices.  Shapes and returns as ``decode_attention``."""
+    b, r, dh = q.shape
+    n = k.shape[2]
+    g = r // hkv
+    own = (jnp.arange(r)[:, None] // g) == (jnp.arange(n)[None] % hkv)
+
+    def step(carry, ci):
+        m, l, acc = carry
+        start = (layer, 0, ci * chunk, 0)
+        kc = jax.lax.dynamic_slice(k, start, (1, b, chunk, dh))[0]
+        vc = jax.lax.dynamic_slice(v, start, (1, b, chunk, dh))[0]
+        ok = (jax.lax.dynamic_slice_in_dim(own, ci * chunk, chunk, axis=1)
+              & (jax.lax.dynamic_slice_in_dim(valid, ci * chunk, chunk,
+                                              axis=2) != 0))
+        s = jnp.einsum("brd,bnd->brn", q, kc,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(ok, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l = corr * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jnp.einsum("brn,bnd->brd", p.astype(vc.dtype), vc,
+                                      preferred_element_type=jnp.float32)
+        return (m_new, l, acc), None
+
+    init = (s1.astype(jnp.float32), jnp.ones_like(s1, jnp.float32),
+            v1.astype(jnp.float32))
+    (m, l, acc), _ = jax.lax.scan(step, init, jnp.arange(n // chunk))
+    return (acc / l).astype(q.dtype), l

@@ -278,49 +278,102 @@ def _lm_prefill(params, cfg, batch, max_len, mca_key=None):
     return {"layers": caches, "pos_off": off_arr}, x, stats
 
 
-def _decode_layer(p_l, cfg, xx, cache_l, t, kind, pos_off=None):
+def _decode_layer(p_l, cfg, xx, cache, layer, t, kind, pos_off=None,
+                  visible=None):
+    """Decode one token through layer ``layer`` of a layer-stacked cache
+    (every leaf ``[L, ...]``).  Returns (x, cache, rows).
+
+    Attention reads the layer's K/V from the stack and returns the new
+    rows, which ``_write_rows`` puts into every layer at once after the
+    layer scan (rows is None otherwise); GQA attends the slots
+    ``visible`` (``attention.gqa_visible``, computed once per step).  A
+    recurrent layer, whose whole state changes every step, reads its
+    slice and writes it back at the same index of the carried stack."""
     h = apply_norm(p_l["ln1"], cfg, xx)
-    if kind == "ssm":
-        y, cache_l = ssm.mamba2_decode(p_l["mixer"], cfg, h, cache_l)
-        return xx + y, cache_l
-    if kind == "rec_ffn":
-        y, cache_l = rglru.recurrent_decode(p_l["mixer"], cfg, h, cache_l)
+    rows = None
+    if kind in ("ssm", "rec_ffn"):
+        decode = (ssm.mamba2_decode if kind == "ssm"
+                  else rglru.recurrent_decode)
+        y, new_l = decode(p_l["mixer"], cfg, h,
+                          jax.tree.map(lambda c: c[layer], cache))
+        cache = jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                c, n.astype(c.dtype), layer, 0), cache, new_l)
         xx = xx + y
+        if kind == "ssm":
+            return xx, cache, rows
     elif cfg.attn_type == "mla":
-        y, cache_l, _ = attn.mla_decode(p_l["mixer"], cfg, h, cache_l, t=t,
-                                        pos_off=pos_off)
+        y, rows, _ = attn.mla_decode(p_l["mixer"], cfg, h, cache, t=t,
+                                     layer=layer, pos_off=pos_off)
         xx = xx + y
     else:
-        y, cache_l, _ = attn.gqa_decode(p_l["mixer"], cfg, h, cache_l, t=t,
-                                        pos_off=pos_off)
+        y, rows, _ = attn.gqa_decode(p_l["mixer"], cfg, h, cache, t=t,
+                                     layer=layer, visible=visible,
+                                     pos_off=pos_off)
         xx = xx + y
     h = apply_norm(p_l["ln2"], cfg, xx)
     if kind == "attn_moe":
         y, _, _ = ffn_mod.moe_ffn(p_l["ffn"], cfg, h)
     else:
         y = ffn_mod.ffn(p_l["ffn"], cfg, h)
-    return xx + y, cache_l
+    return xx + y, cache, rows
+
+
+def _write_rows(cfg, cache, rows, t):
+    """Put a decode step's new attention rows (leaves ``[L, B, 1, ...]``)
+    into the stacked cache in place; a recurrent stack has none."""
+    if rows is None:
+        return cache
+    write = attn.mla_write if cfg.attn_type == "mla" else attn.gqa_write
+    return write(cfg, cache, rows, t)
+
+
+def _visible(cfg, cache, t, kind, pos_off=None):
+    """The step's attendable slots for a stacked GQA cache, else None."""
+    if kind in ("ssm", "rec_ffn") or cfg.attn_type == "mla":
+        return None
+    return attn.gqa_visible(cfg, cache, t, pos_off)
+
+
+def _decode_one_layer(p_l, cfg, xx, cache_l, t, kind):
+    """:func:`_decode_layer` on one layer's own (unstacked) cache."""
+    cache = jax.tree.map(lambda c: c[None], cache_l)
+    xx, cache, rows = _decode_layer(p_l, cfg, xx, cache, 0, t, kind,
+                                    visible=_visible(cfg, cache, t, kind))
+    if rows is not None:
+        cache = _write_rows(cfg, cache, jax.tree.map(lambda r: r[None], rows),
+                            t)
+    return xx, jax.tree.map(lambda c: c[0], cache)
 
 
 def _lm_decode(params, cfg, tokens, cache, t):
-    """tokens: [B, 1]; t: scalar int32. Returns (logits, cache)."""
+    """tokens: [B, 1]; t: scalar or [B] int32. Returns (logits, cache).
+
+    The layer-stacked cache stays one set of buffers through the step:
+    the layer scan reads each layer's slice of it (a recurrent layer also
+    writes its state back in place) and yields the attention layers' new
+    rows, which are then written into the stack in place, B rows per
+    layer.  Nothing cuts the stack into per-layer ``xs`` and stacks
+    fresh ``ys`` again, so the step never copies the stack."""
     x = embed_tokens(params["embed"], tokens)
     kind = stack.layer_kind(cfg)
     if cfg.family == "hybrid":
         return _hybrid_decode(params, cfg, x, cache, t)
     pos_off = cache.get("pos_off")
+    visible = _visible(cfg, cache["layers"], t, kind, pos_off)
 
-    def body(xx, inp):
-        p_l, cache_l = inp
-        xx, new_cache = _decode_layer(p_l, cfg, xx, cache_l, t, kind,
-                                      pos_off=pos_off)
-        return xx, new_cache
+    def body(carry, inp):
+        xx, caches = carry
+        p_l, layer = inp
+        xx, caches, rows = _decode_layer(p_l, cfg, xx, caches, layer, t,
+                                         kind, pos_off, visible)
+        return (xx, caches), rows
 
-    x, new_caches = maybe_scan(body, x, (params["layers"],
-                                         cache["layers"]),
-                               cfg.unroll_layers)
+    (x, new_caches), rows = maybe_scan(
+        body, (x, cache["layers"]),
+        (params["layers"], jnp.arange(cfg.n_layers)), cfg.unroll_layers)
+    new = {"layers": _write_rows(cfg, new_caches, rows, t)}
     x = apply_norm(params["final_norm"], cfg, x)
-    new = {"layers": new_caches}
     if pos_off is not None:
         new["pos_off"] = pos_off
     return _logits(params, cfg, x), new
@@ -397,8 +450,8 @@ def _hybrid_decode(params, cfg, x, cache, t):
         gp, gc = inp
         new_c = {}
         for i, kind in enumerate(pat):
-            xx, new_c[f"pos{i}"] = _decode_layer(gp[f"pos{i}"], cfg, xx,
-                                                 gc[f"pos{i}"], t, kind)
+            xx, new_c[f"pos{i}"] = _decode_one_layer(gp[f"pos{i}"], cfg, xx,
+                                                     gc[f"pos{i}"], t, kind)
         return xx, new_c
 
     x, gcaches = maybe_scan(body, x, (params["layers"]["groups"],
@@ -406,8 +459,8 @@ def _hybrid_decode(params, cfg, x, cache, t):
                             cfg.unroll_layers)
     rem_caches = []
     for i, kind in enumerate(rem):
-        x, c = _decode_layer(params["layers"]["rem"][i], cfg, x,
-                             cache["rem"][i], t, kind)
+        x, c = _decode_one_layer(params["layers"]["rem"][i], cfg, x,
+                                 cache["rem"][i], t, kind)
         rem_caches.append(c)
     x = apply_norm(params["final_norm"], cfg, x)
     return _logits(params, cfg, x), {"groups": gcaches, "rem": rem_caches}
@@ -542,8 +595,13 @@ def _encdec_decode(params, cfg, tokens, cache, t):
     def body(xx, inp):
         p_l, cache_l = inp
         h = apply_norm(p_l["ln1"], cfg, xx)
-        y, new_self, _ = attn.gqa_decode(p_l["mixer"], cfg, h,
-                                         cache_l["self"], t=t)
+        self_c = jax.tree.map(lambda c: c[None], cache_l["self"])
+        y, rows, _ = attn.gqa_decode(
+            p_l["mixer"], cfg, h, self_c, t=t, layer=0,
+            visible=attn.gqa_visible(cfg, self_c, t))
+        new_self = jax.tree.map(
+            lambda c: c[0], attn.gqa_write(
+                cfg, self_c, jax.tree.map(lambda r: r[None], rows), t))
         xx = xx + y
         h = apply_norm(p_l["ln_x"], cfg, xx)
         xx = xx + _cross_decode(p_l["cross"], cfg, h, cache_l["cross_k"],

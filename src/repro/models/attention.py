@@ -532,69 +532,39 @@ def init_gqa_cache(cfg, batch, max_len, dtype):
     }
 
 
-def _decode_attn_chunked(qg, kc, vc, valid, scale, chunk):
-    """Flash-decode: online softmax over cache-slot chunks.
-
-    Avoids materializing the [B,Hkv,G,1,slots] f32 score buffer that
-    dominates decode temp memory at 32k+ contexts (measured 19.4 GB on
-    qwen3 decode_32k with the monolithic softmax).
-
-    qg: [B,1,hkv,g,dh]; kc/vc: [B,slots,hkv,dh]; valid: [B, slots] bool.
-    Returns (out [B,1,hkv,g,dh], a_max [B,1] rowmax probability)."""
-    b = qg.shape[0]
-    hkv, g, dh = qg.shape[2], qg.shape[3], qg.shape[4]
-    slots = kc.shape[1]
-
-    def step(carry, ci):
-        m, l, acc = carry
-        # dynamic slices of the (donated) cache — no moveaxis copy
-        kcb = jax.lax.dynamic_slice_in_dim(kc, ci * chunk, chunk, axis=1)
-        vcb = jax.lax.dynamic_slice_in_dim(vc, ci * chunk, chunk, axis=1)
-        vm = jax.lax.dynamic_slice_in_dim(valid, ci * chunk, chunk, axis=1)
-        s = jnp.einsum("bqhgd,bchd->bhgqc", qg, kcb,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(vm[:, None, None, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p_ = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p_, axis=-1)
-        corr_b = jnp.moveaxis(corr, -1, 1)[..., None]
-        acc = acc * corr_b + jnp.einsum(
-            "bhgqc,bchd->bqhgd", p_.astype(vcb.dtype), vcb,
-            preferred_element_type=jnp.float32)
-        return (m_new, l, acc), None
-
-    m0 = jnp.full((b, hkv, g, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, hkv, g, 1), jnp.float32)
-    acc0 = jnp.zeros((b, 1, hkv, g, dh), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(step, (m0, l0, acc0),
-                                  jnp.arange(slots // chunk))
-    safe_l = jnp.where(l == 0.0, 1.0, l)
-    out = (acc / jnp.moveaxis(safe_l, -1, 1)[..., None]).astype(vc.dtype)
-    # max attention prob per query = exp(m - lse)
-    a_max = jnp.max(jnp.exp(m - (m + jnp.log(safe_l))), axis=(1, 2, 3)
-                    )[:, None]
-    return out, a_max
-
-
 @jax.named_scope("attention")
-def gqa_decode(p, cfg, x, cache, *, t, pos_off=None):
-    """Single-token decode. x: [B, 1, d]; t: scalar or [B] int32 position.
+def gqa_decode(p, cfg, x, cache, *, t, layer, visible, pos_off=None):
+    """Single-token decode of layer ``layer`` of a layer-stacked cache.
+
+    x: [B, 1, d]; cache: {"k", "v": [L, B, slots, hkv, dh], "slot_pos":
+    [L, B, slots]}, the whole stack, read and not written here; layer:
+    scalar int32; t: scalar or [B] int32 position; visible: [B, slots]
+    bool, the slots the step may attend (``gqa_visible``, the same for
+    every layer).
+
+    The query attends to the layer's cache as it was before this step
+    plus the current token's own k/v, joined in the softmax
+    (``kernels.decode_attention``, which reads the layer's K/V straight
+    from the stack).  The new K/V rows are returned, and ``gqa_write``
+    puts every layer's rows into the stack in place once the step's layer
+    scan is done.  The slot they will take is masked out of the old
+    cache, so the attended positions are those of a write-first decode.
 
     A scalar ``t`` is the classic lockstep decode (one shared position); a
     per-row ``t`` vector is the per-slot continuous-batching path, where
     every batch row advances at its own sequence position and K/V land at
-    per-row cache slots (``kernels.kv_slot_update``).
+    per-row cache slots.
 
     pos_off: optional [B] int32 left-padding offsets — slots whose global
     position predates a batch row's first real token are masked for that
     row, and RoPE positions shift to t - pos_off[b].
-    Returns (y, new_cache, rowmax [B,1])."""
+    Returns (y, rows {"k", "v": [B, 1, hkv, dh]}, rowmax [B,1])."""
     b = x.shape[0]
     hkv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
     dh = cfg.d_head
     scale = dh ** -0.5
-    slots = cache["k"].shape[1]
+    slots = cache["k"].shape[2]
+    dt = cache["k"].dtype
     off = jnp.zeros((b,), jnp.int32) if pos_off is None else pos_off
     t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,))
 
@@ -606,30 +576,73 @@ def gqa_decode(p, cfg, x, cache, *, t, pos_off=None):
         k1 = rmsnorm(k1, p["k_norm"], cfg.norm_eps)
     posb = t_vec[:, None] - off[:, None]
     q = apply_rope(q, posb, cfg.rope_theta, cfg.rotary_pct)
-    k1 = apply_rope(k1, posb, cfg.rope_theta, cfg.rotary_pct)
+    k1 = apply_rope(k1, posb, cfg.rope_theta, cfg.rotary_pct).astype(dt)
+    v1 = v1.astype(dt)
 
-    slot = t_vec % slots if cfg.window > 0 else t_vec
-    kc = kernel_ops.kv_slot_update(cache["k"], k1, slot)
-    vc = kernel_ops.kv_slot_update(cache["v"], v1, slot)
-    spos = cache["slot_pos"].at[jnp.arange(b), slot].set(t_vec)
-
-    qg = q.reshape(b, 1, hkv, g, dh)
-    # slot_pos are per-row global (pre-offset) positions, so the rolling-
-    # window wraparound composes with the per-row padding mask
-    valid = (spos >= 0) & (spos >= off[:, None])
-    if slots >= 8192 and slots % 1024 == 0:
-        # flash-decode path: never materialize the full score buffer
-        out, rowmax = _decode_attn_chunked(qg, kc, vc, valid, scale, 1024)
-    else:
-        s = jnp.einsum("bqhgd,bshd->bhgqs", qg, kc,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
-        a = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhgqs,bshd->bqhgd", a.astype(vc.dtype), vc)
-        rowmax = jnp.max(a, axis=(1, 2, 4))                 # [B, 1]
+    qh = q.reshape(b, hkv, g, dh)
+    s1 = jnp.einsum("bhgd,bhd->bhg", qh, k1[:, 0],
+                    preferred_element_type=jnp.float32) * scale
+    s1 = jnp.where((t_vec >= off)[:, None, None], s1, NEG_INF)
+    v1g = jnp.broadcast_to(v1[:, 0, :, None], (b, hkv, g, dh))
+    n_layers = cache["k"].shape[0]
+    fold = lambda c: c.reshape(n_layers, b, slots * hkv, dh)  # noqa: E731
+    out, l = kernel_ops.decode_attention(
+        qh.reshape(b, hkv * g, dh), fold(cache["k"]), fold(cache["v"]),
+        jnp.repeat(visible, hkv, axis=1)[:, None],
+        s1.reshape(b, hkv * g, 1), v1g.reshape(b, hkv * g, dh), layer,
+        scale=scale, hkv=hkv)
+    rowmax = jnp.max(1.0 / l, axis=(1, 2))[:, None]            # [B, 1]
     out = out.reshape(b, 1, cfg.n_heads * dh)
     y = out @ p["wo"]
-    return y, {"k": kc, "v": vc, "slot_pos": spos}, rowmax
+    return y, {"k": k1, "v": v1}, rowmax
+
+
+def gqa_visible(cfg, cache, t, pos_off=None):
+    """[B, slots] bool: the cached slots a decode step at position ``t``
+    may attend, in every layer of the stacked GQA cache.
+
+    Every layer holds the same ``slot_pos`` (prefill, slot insertion and
+    ``gqa_write`` write them alike), so one layer's decides for all.  They
+    are per-row global (pre-offset) positions, so the rolling-window
+    wraparound composes with the per-row padding mask ``pos_off``; the
+    slot this step's token will take is left out, since the token joins
+    the softmax itself."""
+    spos = cache["slot_pos"][0]
+    b, slots = spos.shape
+    t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,))
+    off = jnp.zeros((b,), jnp.int32) if pos_off is None else pos_off
+    slot = _decode_slot(cfg, t_vec, slots)
+    return ((spos >= 0) & (spos >= off[:, None])
+            & (jnp.arange(slots)[None] != slot[:, None]))
+
+
+def _decode_slot(cfg, t_vec, slots):
+    """The cache slot each batch row's token at position ``t_vec`` takes:
+    its position, or that position modulo a rolling window's slots."""
+    return t_vec % slots if cfg.window > 0 else t_vec
+
+
+def gqa_write(cfg, cache, rows, t):
+    """Write one decode step's new rows into every layer of a layer-
+    stacked GQA cache, in place.
+
+    rows: {"k", "v": [L, B, 1, hkv, dh]}, the ``gqa_decode`` rows of all L
+    layers; t: scalar or [B] int32 position.  Each batch row's K/V land in
+    its slot of every layer (``kernels.kv_slot_update``) and the slot's
+    ``slot_pos`` becomes ``t``.  The decode step calls this once, after
+    its layer scan has read the whole stack, so the reads precede the
+    write and nothing forces a copy of the stack."""
+    b, slots = cache["slot_pos"].shape[1:]
+    t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,))
+    slot = _decode_slot(cfg, t_vec, slots)
+    # every layer's slot_pos is the same row: one updated copy, broadcast
+    # (a scatter spanning the layers has XLA relay the stack out and back)
+    spos = cache["slot_pos"]
+    spos = jnp.broadcast_to(spos[0].at[jnp.arange(b), slot].set(t_vec),
+                            spos.shape)
+    return {"k": kernel_ops.kv_slot_update(cache["k"], rows["k"], slot),
+            "v": kernel_ops.kv_slot_update(cache["v"], rows["v"], slot),
+            "slot_pos": spos}
 
 
 # ------------------------------------------------------------ MLA module
@@ -736,9 +749,15 @@ def init_mla_cache(cfg, batch, max_len, dtype):
 
 
 @jax.named_scope("attention")
-def mla_decode(p, cfg, x, cache, *, t, pos_off=None):
-    """Absorbed-matrix MLA decode: scores/value read the latent cache
-    directly; per-token cache cost is (kv_lora + rope) floats.
+def mla_decode(p, cfg, x, cache, *, t, layer, pos_off=None):
+    """Absorbed-matrix MLA decode of layer ``layer`` of a layer-stacked
+    latent cache: scores/value read the latent cache directly; per-token
+    cache cost is (kv_lora + rope) floats.
+
+    cache: {"ckv": [L, B, S, kv_lora], "kr": [L, B, S, rope]}, the whole
+    stack, read and not written here.  As in :func:`gqa_decode` the query
+    attends to the old cache plus the current token's own latents, joined
+    in the softmax, and the new rows are returned for ``mla_write``.
 
     ``t`` may be a scalar (lockstep decode) or a [B] vector (per-slot
     continuous batching — each row writes/reads at its own position)."""
@@ -747,6 +766,7 @@ def mla_decode(p, cfg, x, cache, *, t, pos_off=None):
     dn, dr, dv = cfg.mla_qk_nope, cfg.mla_qk_rope, cfg.mla_v_dim
     dl = cfg.mla_kv_lora
     scale = (dn + dr) ** -0.5
+    dt = cache["ckv"].dtype
     off = jnp.zeros((b,), jnp.int32) if pos_off is None else pos_off
     t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,))
 
@@ -756,29 +776,55 @@ def mla_decode(p, cfg, x, cache, *, t, pos_off=None):
     posb = t_vec[:, None] - off[:, None]
     q_rope = apply_rope(q_rope, posb, cfg.rope_theta)
 
-    ckv1 = rmsnorm(x @ p["w_dkv"], p["kv_ln"], cfg.norm_eps)  # [B,1,dl]
+    ckv1 = rmsnorm(x @ p["w_dkv"], p["kv_ln"], cfg.norm_eps
+                   ).astype(dt)                               # [B,1,dl]
     kr1 = apply_rope((x @ p["w_kr"])[:, :, None, :], posb,
-                     cfg.rope_theta)[:, :, 0, :]              # [B,1,dr]
-    ckv = kernel_ops.kv_slot_update(cache["ckv"], ckv1, t_vec)
-    kr = kernel_ops.kv_slot_update(cache["kr"], kr1, t_vec)
+                     cfg.rope_theta)[:, :, 0, :].astype(dt)   # [B,1,dr]
+    ckv = cache["ckv"][layer]
+    kr = cache["kr"][layer]
 
     # absorb W_UK into the query:  q_lat[b,h,dl] = q_nope . W_UK[:, h, :]
     w_uk = p["w_uk"].reshape(dl, h, dn)
     q_lat = jnp.einsum("bqhd,lhd->bqhl", q_nope, w_uk)
-    s_lat = jnp.einsum("bqhl,bsl->bhqs", q_lat, ckv,
-                       preferred_element_type=jnp.float32)
-    s_rot = jnp.einsum("bqhd,bsd->bhqs", q_rope, kr,
-                       preferred_element_type=jnp.float32)
-    s = (s_lat + s_rot) * scale
+
+    # bf16 operands widen exactly, so f32 products are the bf16 ones
+    f32 = jnp.float32
+    q_lat, q_rope = q_lat.astype(f32), q_rope.astype(f32)
+    s = (jnp.einsum("bqhl,bsl->bhqs", q_lat, ckv.astype(f32))
+         + jnp.einsum("bqhd,bsd->bhqs", q_rope, kr.astype(f32))) * scale
+    s1 = (jnp.einsum("bqhl,bql->bhq", q_lat, ckv1.astype(f32))
+          + jnp.einsum("bqhd,bqd->bhq", q_rope, kr1.astype(f32))
+          )[..., None] * scale                                # [B,h,1,1]
     idxs = jnp.arange(ckv.shape[1])
-    valid = ((idxs[None, :] <= t_vec[:, None])
+    valid = ((idxs[None, :] < t_vec[:, None])
              & (idxs[None, :] >= off[:, None]))
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    a = jax.nn.softmax(s, axis=-1)
-    out_lat = jnp.einsum("bhqs,bsl->bqhl", a.astype(ckv.dtype), ckv)
+    s1 = jnp.where((t_vec >= off)[:, None, None, None], s1, NEG_INF)
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s1)
+    e = jnp.exp(s - m)
+    e1 = jnp.exp(s1 - m)
+    l = jnp.sum(e, axis=-1, keepdims=True) + e1
+    a, a1 = e / l, e1 / l
+    out_lat = (jnp.einsum("bhqs,bsl->bqhl", a.astype(dt).astype(f32),
+                          ckv.astype(f32))
+               + jnp.einsum("bhqs,bsl->bqhl", a1.astype(dt).astype(f32),
+                            ckv1.astype(f32)))
+    out_lat = out_lat.astype(dt)
     # absorb W_UV on the way out
     w_uv = p["w_uv"].reshape(dl, h, dv)
     out = jnp.einsum("bqhl,lhv->bqhv", out_lat, w_uv).reshape(b, 1, h * dv)
     y = out @ p["wo"]
-    rowmax = jnp.max(a, axis=(1, 3))
-    return y, {"ckv": ckv, "kr": kr}, rowmax
+    rowmax = jnp.maximum(jnp.max(a, axis=(1, 3)), jnp.max(a1, axis=(1, 3)))
+    return y, {"ckv": ckv1, "kr": kr1}, rowmax
+
+
+def mla_write(cfg, cache, rows, t):
+    """Write one decode step's new latent rows (``mla_decode``'s, of all L
+    layers: {"ckv": [L, B, 1, kv_lora], "kr": [L, B, 1, rope]}) into every
+    layer of the stacked latent cache at position ``t``, in place (see
+    ``gqa_write``)."""
+    del cfg
+    b = cache["ckv"].shape[1]
+    t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,))
+    return {name: kernel_ops.kv_slot_update(cache[name], rows[name], t_vec)
+            for name in ("ckv", "kr")}

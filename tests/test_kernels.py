@@ -179,14 +179,16 @@ def test_tiered_dispatch_kernel_path_matches_jnp():
     (3, 12, (5, 7)),        # not lane-aligned -> scatter fallback
 ])
 def test_kv_slot_update_per_row_write(b, s, trail):
+    """Every layer of a 3-layer stack takes its own new row at each batch
+    row's position, and nothing else moves."""
     from repro.kernels import kv_slot_update
     key = jax.random.PRNGKey(b * 100 + s)
     kc, kn = jax.random.split(key)
-    cache = jax.random.normal(kc, (b, s) + trail)
-    new = jax.random.normal(kn, (b, 1) + trail)
+    cache = jax.random.normal(kc, (3, b, s) + trail)
+    new = jax.random.normal(kn, (3, b, 1) + trail)
     pos = jnp.asarray([(3 * i + 1) % s for i in range(b)], jnp.int32)
     out = kv_slot_update(cache, new, pos)
-    ref = cache.at[jnp.arange(b), pos].set(new[:, 0])
+    ref = cache.at[:, jnp.arange(b), pos].set(new[:, :, 0])
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0, atol=0)
 
@@ -197,14 +199,76 @@ def test_kv_slot_update_dispatch_counters():
     from repro import obs
     from repro.kernels import kv_slot_update
     with obs.scoped() as reg:
-        kv_slot_update(jnp.zeros((2, 4, 1, 128)), jnp.ones((2, 1, 1, 128)),
-                       jnp.zeros(2, jnp.int32))
-        kv_slot_update(jnp.zeros((2, 4, 5)), jnp.ones((2, 1, 5)),
+        kv_slot_update(jnp.zeros((2, 2, 4, 1, 128)),
+                       jnp.ones((2, 2, 1, 1, 128)), jnp.zeros(2, jnp.int32))
+        kv_slot_update(jnp.zeros((2, 2, 4, 5)), jnp.ones((2, 2, 1, 5)),
                        jnp.zeros(2, jnp.int32))
         snap = reg.snapshot()
     c = snap["counters"]
     assert c["kernels.kv_slot_update.kernel_calls"] == 1
     assert c["kernels.kv_slot_update.fallback_calls"] == 1
+
+
+# ------------------------------------------------------ decode_attention
+@pytest.mark.parametrize("block_b,block_n", [(4, 256), (2, 128), (1, 64)])
+def test_decode_attention_matches_softmax(block_b, block_n):
+    """The stacked-cache decode kernel equals a materialized softmax over
+    the current token plus the valid rows of its own kv head, in layer 1
+    of a 3-layer stack, whatever its blocking (one block, or an online
+    softmax across several)."""
+    from repro.kernels.decode_attention import decode_attention
+    l, b, slots, hkv, g, dh = 3, 4, 128, 2, 3, 128
+    n, r = slots * hkv, hkv * g
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    q = jax.random.normal(ks[0], (b, r, dh))
+    k = jax.random.normal(ks[1], (l, b, n, dh))
+    v = jax.random.normal(ks[2], (l, b, n, dh))
+    valid = jax.random.bernoulli(ks[3], 0.7, (b, 1, n)).astype(jnp.int32)
+    s1 = jax.random.normal(ks[4], (b, r, 1))
+    v1 = jax.random.normal(ks[5], (b, r, dh))
+    scale = dh ** -0.5
+    out, den = decode_attention(q, k, v, valid, s1, v1, jnp.int32(1),
+                                scale=scale, hkv=hkv, block_b=block_b,
+                                block_n=block_n, interpret=True)
+    s = jnp.einsum("brd,bnd->brn", q, k[1]) * scale
+    own = (jnp.arange(r)[:, None] // g) == (jnp.arange(n)[None] % hkv)
+    s = jnp.where(own & (valid != 0), s, kref.NEG_INF)
+    a = jax.nn.softmax(jnp.concatenate([s1, s], axis=-1), axis=-1)
+    want = a[..., :1] * v1 + jnp.einsum("brn,bnd->brd", a[..., 1:], v[1])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               **_tol(jnp.float32))
+    # a head's largest attention probability is 1 / den
+    np.testing.assert_allclose(np.asarray(1.0 / den[..., 0]),
+                               np.asarray(jnp.max(a, axis=-1)),
+                               **_tol(jnp.float32))
+    ref_out, ref_den = kref.ref_decode_attention(
+        q, k, v, valid, s1, v1, jnp.int32(1), scale=scale, hkv=hkv,
+        chunk=block_n)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               **_tol(jnp.float32))
+    np.testing.assert_allclose(np.asarray(den), np.asarray(ref_den),
+                               **_tol(jnp.float32))
+
+
+def test_decode_attention_dispatch():
+    """Whole-lane head dims take the kernel, others the jnp fallback —
+    recorded at dispatch time in repro.obs."""
+    from repro import obs
+    from repro.kernels.decode_attention import pick_blocks
+    from repro.kernels.ops import decode_attention
+    assert pick_blocks(64, 2048, 128, 2) == (4, 2048)
+    assert pick_blocks(16, 8448, 128, 2) == (1, 4224)
+    assert pick_blocks(4, 64, 32, 4) is None
+    with obs.scoped() as reg:
+        for dh in (128, 32):
+            decode_attention(jnp.zeros((2, 2, dh)), jnp.zeros((1, 2, 8, dh)),
+                             jnp.zeros((1, 2, 8, dh)),
+                             jnp.ones((2, 1, 8), jnp.int32),
+                             jnp.zeros((2, 2, 1)), jnp.zeros((2, 2, dh)),
+                             jnp.int32(0), scale=1.0, hkv=2)
+        c = reg.snapshot()["counters"]
+    assert c["kernels.decode_attention.kernel_calls"] == 1
+    assert c["kernels.decode_attention.fallback_calls"] == 1
 
 
 # ------------------------------------------------------ device telemetry
@@ -225,34 +289,36 @@ class TestDeviceTelemetry:
     def test_kv_update_scan_counts_every_launch_kernel_path(self):
         from repro.kernels import kv_slot_update
         from repro.obs import devtel
-        b, s, f, steps = 2, 16, 128, 5
+        n_layers, b, s, f, steps = 2, 2, 16, 128, 5
         with devtel.enabled_scope():
             @jax.jit
             def burst(cache, new, pos):
                 def body(c, i):
                     return kv_slot_update(c, new, pos + i), ()
                 return jax.lax.scan(body, cache, jnp.arange(steps))[0]
-            d = self._deltas(lambda: burst(jnp.zeros((b, s, 1, f)),
-                                           jnp.ones((b, 1, 1, f)),
-                                           jnp.zeros(b, jnp.int32)))
+            d = self._deltas(lambda: burst(
+                jnp.zeros((n_layers, b, s, 1, f)),
+                jnp.ones((n_layers, b, 1, 1, f)), jnp.zeros(b, jnp.int32)))
         assert d["kernels.kv_slot_update.device_launches"] == steps
-        assert d["kernels.kv_slot_update.device_rows_written"] == steps * b
+        assert (d["kernels.kv_slot_update.device_rows_written"]
+                == steps * n_layers * b)
 
     def test_kv_update_scan_counts_every_launch_fallback_path(self):
         from repro.kernels import kv_slot_update
         from repro.obs import devtel
-        b, s, f, steps = 3, 16, 96, 4          # f % 128 != 0 -> scatter
+        n_layers, b, s, f, steps = 2, 3, 16, 96, 4   # f % 128 -> scatter
         with devtel.enabled_scope():
             @jax.jit
             def burst(cache, new, pos):
                 def body(c, i):
                     return kv_slot_update(c, new, pos + i), ()
                 return jax.lax.scan(body, cache, jnp.arange(steps))[0]
-            d = self._deltas(lambda: burst(jnp.zeros((b, s, f)),
-                                           jnp.ones((b, 1, f)),
-                                           jnp.zeros(b, jnp.int32)))
+            d = self._deltas(lambda: burst(
+                jnp.zeros((n_layers, b, s, f)),
+                jnp.ones((n_layers, b, 1, f)), jnp.zeros(b, jnp.int32)))
         assert d["kernels.kv_slot_update.device_launches"] == steps
-        assert d["kernels.kv_slot_update.device_rows_written"] == steps * b
+        assert (d["kernels.kv_slot_update.device_rows_written"]
+                == steps * n_layers * b)
 
     def test_mca_fixed_sampled_blocks_kernel_path(self):
         from repro.obs import devtel
@@ -385,7 +451,7 @@ class TestDeviceTelemetry:
             def one(cache, new, pos):
                 from repro.kernels import kv_slot_update
                 return kv_slot_update(cache, new, pos)
-            args = (jnp.zeros((b, s, 1, f)), jnp.ones((b, 1, 1, f)),
+            args = (jnp.zeros((1, b, s, 1, f)), jnp.ones((1, b, 1, 1, f)),
                     jnp.zeros(b, jnp.int32))
             jax.block_until_ready(one(*args))    # activity BEFORE scope
             devtel.sync()

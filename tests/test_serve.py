@@ -230,3 +230,208 @@ def test_slot_batcher_eos_and_deadline(engine_setup):
     sb2.submit(Request(uid=1, prompt=p, max_new=8, deadline_s=-1.0))
     out = sb2.run()
     assert sb2.status[1] == "timeout" and 1 not in out
+
+
+# ------------------------------------------- in-place layer-stacked decode
+def _ref_gqa_decode(p, cfg, x, c, t_vec, off):
+    """The per-layer decode as it was before the cache rode in the layer
+    scan's carry: write the new K/V row into the layer's own cache first,
+    then attend over the whole of it."""
+    from repro.models.attention import NEG_INF, _split_heads
+    from repro.models.common import apply_rope, rmsnorm
+    b = x.shape[0]
+    hkv, g, dh = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.d_head
+    slots = c["k"].shape[1]
+    q = _split_heads(x @ p["wq"], cfg.n_heads, dh)
+    k1 = _split_heads(x @ p["wk"], hkv, dh)
+    v1 = _split_heads(x @ p["wv"], hkv, dh)
+    if cfg.qk_norm:
+        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+        k1 = rmsnorm(k1, p["k_norm"], cfg.norm_eps)
+    posb = t_vec[:, None] - off[:, None]
+    q = apply_rope(q, posb, cfg.rope_theta, cfg.rotary_pct)
+    k1 = apply_rope(k1, posb, cfg.rope_theta, cfg.rotary_pct)
+    slot = t_vec % slots if cfg.window > 0 else t_vec
+    rows = jnp.arange(b)
+    kc = c["k"].at[rows, slot].set(k1[:, 0])
+    vc = c["v"].at[rows, slot].set(v1[:, 0])
+    spos = c["slot_pos"].at[rows, slot].set(t_vec)
+    valid = (spos >= 0) & (spos >= off[:, None])
+    s = jnp.einsum("bqhgd,bshd->bhgqs", q.reshape(b, 1, hkv, g, dh), kc,
+                   preferred_element_type=jnp.float32) * dh ** -0.5
+    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
+    a = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhgqs,bshd->bqhgd", a.astype(vc.dtype), vc)
+    y = out.reshape(b, 1, cfg.n_heads * dh) @ p["wo"]
+    return y, {"k": kc, "v": vc, "slot_pos": spos}
+
+
+def _ref_mla_decode(p, cfg, x, c, t_vec, off):
+    """Write-first absorbed MLA decode of one layer's own latent cache."""
+    from repro.models.attention import NEG_INF, _split_heads
+    from repro.models.common import apply_rope, rmsnorm
+    b, h = x.shape[0], cfg.n_heads
+    dn, dr, dv = cfg.mla_qk_nope, cfg.mla_qk_rope, cfg.mla_v_dim
+    dl = cfg.mla_kv_lora
+    q = _split_heads(rmsnorm(x @ p["w_dq"], p["q_ln"], cfg.norm_eps)
+                     @ p["w_uq"], h, dn + dr)
+    posb = t_vec[:, None] - off[:, None]
+    q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], posb,
+                                             cfg.rope_theta)
+    ckv1 = rmsnorm(x @ p["w_dkv"], p["kv_ln"], cfg.norm_eps)
+    kr1 = apply_rope((x @ p["w_kr"])[:, :, None, :], posb,
+                     cfg.rope_theta)[:, :, 0, :]
+    rows = jnp.arange(b)
+    ckv = c["ckv"].at[rows, t_vec].set(ckv1[:, 0])
+    kr = c["kr"].at[rows, t_vec].set(kr1[:, 0])
+    q_lat = jnp.einsum("bqhd,lhd->bqhl", q_nope, p["w_uk"].reshape(dl, h, dn))
+    s = (jnp.einsum("bqhl,bsl->bhqs", q_lat, ckv,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bqhd,bsd->bhqs", q_rope, kr,
+                      preferred_element_type=jnp.float32)) * (dn + dr) ** -0.5
+    idx = jnp.arange(ckv.shape[1])
+    valid = (idx[None] <= t_vec[:, None]) & (idx[None] >= off[:, None])
+    a = jax.nn.softmax(jnp.where(valid[:, None, None, :], s, NEG_INF), -1)
+    out_lat = jnp.einsum("bhqs,bsl->bqhl", a.astype(ckv.dtype), ckv)
+    out = jnp.einsum("bqhl,lhv->bqhv", out_lat, p["w_uv"].reshape(dl, h, dv))
+    return out.reshape(b, 1, h * dv) @ p["wo"], {"ckv": ckv, "kr": kr}
+
+
+def _ref_decode(params, cfg, tokens, cache, t):
+    """Step-by-step reference: every layer decodes on its own slice of the
+    cache, and the new slices are stacked again afterwards."""
+    from repro.models import ffn as ffn_mod
+    from repro.models import ssm, stack
+    from repro.models.api import _logits
+    from repro.models.common import apply_norm, embed_tokens
+    b = tokens.shape[0]
+    kind = stack.layer_kind(cfg)
+    t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,))
+    off = cache["pos_off"]
+    x = embed_tokens(params["embed"], tokens)
+    new = []
+    for i in range(cfg.n_layers):
+        p_l = jax.tree.map(lambda a: a[i], params["layers"])
+        c_l = jax.tree.map(lambda a: a[i], cache["layers"])
+        h = apply_norm(p_l["ln1"], cfg, x)
+        if kind == "ssm":
+            y, c_l = ssm.mamba2_decode(p_l["mixer"], cfg, h, c_l)
+            x = x + y
+        else:
+            attend = (_ref_mla_decode if cfg.attn_type == "mla"
+                      else _ref_gqa_decode)
+            y, c_l = attend(p_l["mixer"], cfg, h, c_l, t_vec, off)
+            x = x + y
+            h = apply_norm(p_l["ln2"], cfg, x)
+            x = x + (ffn_mod.moe_ffn(p_l["ffn"], cfg, h)[0]
+                     if kind == "attn_moe" else ffn_mod.ffn(p_l["ffn"], cfg, h))
+        new.append(c_l)
+    x = apply_norm(params["final_norm"], cfg, x)
+    layers = jax.tree.map(lambda *a: jnp.stack(a), *new)
+    return _logits(params, cfg, x), {"layers": layers, "pos_off": off}
+
+
+_INPLACE_CASES = {
+    "gqa": ("starcoder2-3b", {}),
+    # [kv_heads, 128] rows: the Pallas KV write and decode attention
+    "gqa-kernels": ("starcoder2-3b", {"d_head": 128}),
+    "gqa-window": ("starcoder2-3b", {"window": 8}),
+    "mla": ("minicpm3-4b", {}),
+    "moe": ("olmoe-1b-7b", {}),
+    "ssm": ("mamba2-2.7b", {}),
+}
+
+
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar-t", "row-t"])
+@pytest.mark.parametrize("case", sorted(_INPLACE_CASES))
+def test_inplace_decode_matches_per_layer_reference(case, per_row):
+    """Several decode steps with the layer-stacked cache kept in place give
+    the tokens, logits and caches of the per-layer write-first decode,
+    within bf16 round-off.  The per-row case puts the
+    rows at different positions (the second rewrites a slot it already
+    holds) and left-pads one row; the window case wraps the rolling
+    cache."""
+    arch, over = _INPLACE_CASES[case]
+    cfg = reduced(get_config(arch), dtype="bfloat16", **over)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(3))
+    b, s, steps = 2, 12, 4
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(4), (b, s), 0,
+                                          cfg.vocab_size)}
+    if per_row and case != "ssm":          # a recurrent state has no padding
+        batch["pos_offset"] = jnp.asarray([0, 3], jnp.int32)
+    cache, hidden, _ = model.prefill(params, batch, 24)
+    from repro.models.api import _logits
+    tok = jnp.argmax(_logits(params, cfg, hidden[:, -1:])
+                     [..., :cfg.vocab_size], axis=-1).astype(jnp.int32)
+    t = jnp.asarray([s, s - 2], jnp.int32) if per_row else jnp.int32(s)
+    ref_cache, decode = cache, jax.jit(model.decode)
+    ref = jax.jit(_ref_decode, static_argnums=1)
+    for _ in range(steps):
+        logits, cache = decode(params, tok, cache, t)
+        ref_logits, ref_cache = ref(params, cfg, tok, ref_cache, t)
+        _assert_bf16_close(logits, ref_logits)
+        _assert_same_token(logits[:, 0, :cfg.vocab_size],
+                           ref_logits[:, 0, :cfg.vocab_size])
+        ref_tok = jnp.argmax(ref_logits[..., :cfg.vocab_size], axis=-1)
+        tok, t = ref_tok.astype(jnp.int32), t + 1
+    for got, want in zip(jax.tree.leaves(cache), jax.tree.leaves(ref_cache)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        _assert_bf16_close(got, want)
+
+
+def _assert_same_token(got, want):
+    """Each row picks the reference's best token, or one whose reference
+    logit is within bf16 round-off of the best (random weights make near
+    ties, which rounding may break either way)."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    picked = want[np.arange(len(want)), got.argmax(-1)]
+    tol = 4 * float(jnp.finfo(jnp.bfloat16).eps) * np.abs(want).max()
+    assert np.all(want.max(-1) - picked <= tol), (want.max(-1) - picked, tol)
+
+
+def _assert_bf16_close(got, want):
+    """Within bf16 round-off: the error's norm is at most two bf16 epsilons
+    of the reference's (a scan and a Python loop over the same layers
+    already differ by about one)."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    eps = float(jnp.finfo(jnp.bfloat16).eps)
+    assert (np.linalg.norm(got - want)
+            <= 2 * eps * np.linalg.norm(want)), (
+        np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize("arch,path", [("starcoder2-3b", "kernel_calls"),
+                                       ("minicpm3-4b", "fallback_calls")])
+def test_inplace_decode_kv_write_accounting(arch, path):
+    """The in-place decode still writes B rows per cache leaf per layer per
+    step through ``kv_slot_update``, one call per leaf and step for all
+    layers: the device telemetry counts them, and the dispatch counter
+    names the path that wrote them (the GQA cache's [kv_heads, 128] rows
+    are whole tiles, the MLA latent's are not)."""
+    from repro import obs
+    from repro.obs import devtel
+    cfg = reduced(get_config(arch), d_head=128)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    b, steps = 2, 3
+    with obs.scoped() as reg, devtel.enabled_scope():
+        @jax.jit
+        def run(cache, tok, t):
+            def step(c, i):
+                return model.decode(params, tok, c, t + i)[1], ()
+            return jax.lax.scan(step, cache, jnp.arange(steps))[0]
+        base = devtel.totals()
+        jax.block_until_ready(run(model.init_cache(b, 16),
+                                  jnp.ones((b, 1), jnp.int32),
+                                  jnp.asarray([0, 5], jnp.int32)))
+        d = devtel.since(base)
+        counters = reg.snapshot()["counters"]
+    # one write per cache leaf per step, of B rows in each of the layers
+    assert d["kernels.kv_slot_update.device_launches"] == steps * 2
+    assert (d["kernels.kv_slot_update.device_rows_written"]
+            == steps * 2 * cfg.n_layers * b)
+    assert counters[f"kernels.kv_slot_update.{path}"] == 2
+    other = {"kernel_calls": "fallback_calls",
+             "fallback_calls": "kernel_calls"}[path]
+    assert counters.get(f"kernels.kv_slot_update.{other}", 0) == 0

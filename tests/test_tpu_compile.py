@@ -80,11 +80,13 @@ def _copies_of(hlo: str, shape) -> int:
 
 
 def _kv_write(one_chip, cache_shape, dtype, telemetry=False):
-    new_shape = (cache_shape[0], 1) + tuple(cache_shape[2:])
+    """The row write into both layers of a 2-layer stack of
+    ``cache_shape`` (one layer's [B, S, ...]) caches."""
+    new_shape = (2, cache_shape[0], 1) + tuple(cache_shape[2:])
     return jax.jit(
         lambda c, n, p: kv_slot_update(c, n, p, interpret=False,
                                        telemetry=telemetry),
-        donate_argnums=0).lower(_sds(one_chip, cache_shape, dtype),
+        donate_argnums=0).lower(_sds(one_chip, (2,) + cache_shape, dtype),
                                 _sds(one_chip, new_shape, dtype),
                                 _sds(one_chip, (SLOTS,), jnp.int32))
 
@@ -92,11 +94,11 @@ def _kv_write(one_chip, cache_shape, dtype, telemetry=False):
 @pytest.mark.parametrize("telemetry", [False, True])
 def test_kv_slot_update_compiles(one_chip, telemetry):
     """The GQA cache write at StarCoder2-3B widths compiles to the kernel
-    alone: the donated cache is written in place, never copied."""
+    alone: the donated stack is written in place, never copied."""
     shape = (SLOTS, MAX_LEN, N_KV, D_HEAD)
     compiled = _assert_kernel(_kv_write(one_chip, shape, jnp.bfloat16,
                                         telemetry))
-    assert _copies_of(compiled.as_text(), shape) == 0
+    assert _copies_of(compiled.as_text(), (2,) + shape) == 0
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
@@ -152,17 +154,20 @@ def test_attn_colmax_compiles(one_chip):
         scale=D_HEAD ** -0.5, interpret=False))
 
 
-def _burst(one_chip):
-    model = build_model(get_config("starcoder2-3b", n_layers=2))
-    eng = Engine(model, None, batch_size=SLOTS, max_len=MAX_LEN)
+def _burst(one_chip, n_layers=2, slots=SLOTS):
+    model = build_model(get_config("starcoder2-3b", n_layers=n_layers))
+    eng = Engine(model, None, batch_size=slots, max_len=MAX_LEN)
     place = lambda a: _sds(one_chip, a.shape, a.dtype)     # noqa: E731
     params = jax.tree.map(place, jax.eval_shape(model.init,
                                                 jax.random.PRNGKey(0)))
     cache = jax.tree.map(place, jax.eval_shape(
-        lambda: model.init_cache(SLOTS, MAX_LEN)))
-    vec = _sds(one_chip, (SLOTS,), jnp.int32)
+        lambda: model.init_cache(slots, MAX_LEN)))
+    vec = _sds(one_chip, (slots,), jnp.int32)
     return eng._make_burst(8, None).lower(
-        params, _sds(one_chip, (SLOTS, 1), jnp.int32), cache, vec, vec)
+        params, _sds(one_chip, (slots, 1), jnp.int32), cache, vec, vec)
+
+
+_KV_WRITE = re.compile(r"%kv_slot_update[.\d]* = \S+ custom-call\(")
 
 
 def test_decode_burst_compiles(one_chip, monkeypatch):
@@ -173,11 +178,39 @@ def test_decode_burst_compiles(one_chip, monkeypatch):
     layer's cache and no more temporary memory."""
     monkeypatch.setattr(kernel_ops, "_interpret", lambda: False)
     kernel = _assert_kernel(_burst(one_chip))
+    assert _KV_WRITE.search(kernel.as_text())
     monkeypatch.setattr(cache_update, "row_dma_ok", lambda *a: False)
     scatter = _burst(one_chip).compile()
-    assert "tpu_custom_call" not in scatter.as_text()
+    assert not _KV_WRITE.search(scatter.as_text())
     layer = (SLOTS, MAX_LEN, N_KV, D_HEAD)
     assert (_copies_of(kernel.as_text(), layer)
             <= _copies_of(scatter.as_text(), layer))
     assert (kernel.memory_analysis().temp_size_in_bytes
             <= scatter.memory_analysis().temp_size_in_bytes)
+
+
+def _stack_rewrites(hlo: str, shape) -> list:
+    """Copies and dynamic-update-slices (alone or fused) in ``hlo`` that
+    produce a ``shape`` array."""
+    dims = r"\[" + ",".join(map(str, shape)) + r"\]"
+    ops = re.findall(r"%([\w.-]+) = \w+" + dims + r"\{[^}]*\} ([\w-]+)\(",
+                     hlo)
+    return [name for name, op in ops
+            if any(w in name or w in op
+                   for w in ("copy", "dynamic-update-slice"))]
+
+
+def test_decode_burst_keeps_cache_in_place(one_chip, monkeypatch):
+    """The batch-gen cell's burst (all 30 layers, 64 slots x 1024, on the
+    KV write and decode attention kernels) updates the layer-stacked cache
+    in place: nothing copies or rebuilds a whole stacked leaf, and its
+    temporaries stay under the size of one stacked K leaf."""
+    monkeypatch.setattr(kernel_ops, "_interpret", lambda: False)
+    n_layers, slots = 30, 64
+    compiled = _assert_kernel(_burst(one_chip, n_layers, slots))
+    hlo = compiled.as_text()
+    k_leaf = (n_layers, slots, MAX_LEN, N_KV, D_HEAD)
+    assert _stack_rewrites(hlo, k_leaf) == []
+    assert _stack_rewrites(hlo, k_leaf[:3]) == []             # slot_pos
+    k_bytes = 2 * n_layers * slots * MAX_LEN * N_KV * D_HEAD
+    assert compiled.memory_analysis().temp_size_in_bytes < k_bytes
