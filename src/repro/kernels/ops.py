@@ -227,7 +227,8 @@ def flash_attention(q, k, v, *, scale, causal=True, block_q=128, block_k=128):
     """Flash attention fwd; returns (out, lse).
 
     Device telemetry: ``device_tiles`` counts score tiles actually
-    computed in-kernel (causally skipped tiles excluded); the dense
+    computed in-kernel, per query head (causally skipped tiles
+    excluded); the dense
     fallback reports 0 tiles (no tiling), launches still count 1 per
     execution.
     """
@@ -250,6 +251,38 @@ def flash_attention(q, k, v, *, scale, causal=True, block_q=128, block_k=128):
         return _flash_mod.flash_attention(
             q, k, v, scale=scale, causal=causal, block_q=bq,
             block_k=bk, interpret=_interpret())
+
+
+def flash_prefill_tiles(s: int, dh: int) -> bool:
+    """Whether a causal prefill of ``s`` positions with head dim ``dh``
+    tiles for :func:`flash_prefill` (``flash_attention.prefill_blocks``)."""
+    return _flash_mod.prefill_blocks(s, dh) is not None
+
+
+def flash_prefill(q, k, v, pos_offset, *, scale, n_kv_heads):
+    """Causal self-attention of a cache-filling prefill on the flash
+    kernel: q [B, S, Hq * dh], k, v [B, S, Hkv * dh], pos_offset [B]
+    int32 left-padding counts or None.  Returns out [B, S, Hq * dh].
+
+    Callers check :func:`flash_prefill_tiles` first; the kernel has no
+    backward, so only a forward that is never differentiated may call it.
+    Counted as ``kernels.flash_attention.kernel_calls``.
+
+    Device telemetry: ``device_tiles`` counts the score tiles computed,
+    per query head (causal and padding tiles skipped).
+    """
+    bq, bk = _flash_mod.prefill_blocks(q.shape[1], k.shape[2] // n_kv_heads)
+    _count("flash_attention", True)
+    kw = dict(n_kv_heads=n_kv_heads, scale=scale, block_q=bq, block_k=bk,
+              interpret=_interpret())
+    with jax.named_scope("flash_attention"):
+        if devtel.enabled():
+            out, tel = _flash_mod.flash_prefill(q, k, v, pos_offset,
+                                                telemetry=True, **kw)
+            _emit_tel("flash_attention", "device_tiles",
+                      tel[0, LANE_LAUNCH], tel[0, LANE_COUNT])
+            return out
+        return _flash_mod.flash_prefill(q, k, v, pos_offset, **kw)
 
 
 def attn_colmax(q, k, lse, *, scale, causal=True, block_q=128, block_k=128,

@@ -255,7 +255,8 @@ def _lm_prefill(params, cfg, batch, max_len, mca_key=None):
         else:
             y, (k, v), st, _ = attn.gqa_attention(
                 p_l["mixer"], cfg, h, pos=pos, mca_key=key_l,
-                return_kv=True, kv_valid=kv_valid)
+                return_kv=True,
+                pos_offset=None if off is None else off_arr)
             stats = stack._add_stats(stats, st)
             xx = xx + y
             cache_l = _gqa_prefill_cache(cfg, k, v, max_len, cfg.window)

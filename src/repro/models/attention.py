@@ -6,9 +6,10 @@ Layout convention: activations are [B, S, H, dh] (seq-major); GQA never
 materializes repeated KV (einsum over grouped heads).
 
 The chunked two-pass structure mirrors kernels/flash_attention.py +
-kernels/attn_colmax.py; on TPU the Pallas kernels replace passes 1+2 (the
-wrapper picks the implementation), on CPU/dry-run the lax.scan path lowers
-to HLO that XLA pipelines, with identical FLOPs/bytes structure.
+kernels/attn_colmax.py.  A cache-filling GQA prefill with MCA off runs on
+the flash kernel where its shapes tile (``_use_flash_prefill``, on every
+backend: interpret mode on the CPU); everything else, training included,
+runs the lax.scan passes.
 """
 from __future__ import annotations
 
@@ -386,16 +387,30 @@ def _acc_stats(acc, s):
     return out
 
 
+def _use_flash_prefill(cfg, *, return_kv, kv_x, causal, window, mca_on, nm,
+                       s):
+    """Whether a full-sequence GQA call runs on the flash prefill kernel:
+    a causal self-attention that fills a cache (so it is never
+    differentiated, and the kernel needs no backward), with MCA off on
+    V and O, no window narrower than the sequence, no model-axis
+    sharding, and shapes that tile (``kernels.ops.flash_prefill_tiles``).
+    Everything else runs the scan passes."""
+    return (return_kv and kv_x is None and causal and not mca_on
+            and (window == 0 or window >= s) and nm == 1
+            and kernel_ops.flash_prefill_tiles(s, cfg.d_head))
+
+
 @jax.named_scope("attention")
 def gqa_attention(p, cfg, x, *, pos, mca_key=None, causal=None,
-                  window=None, kv_x=None, return_kv=False, kv_valid=None):
+                  window=None, kv_x=None, return_kv=False, pos_offset=None):
     """Full-sequence (train / prefill) GQA attention with MCA on V/O.
 
     x: [B, S, d]; kv_x: cross-attention source (defaults to x);
-    kv_valid: optional [B, S] bool marking real (non-left-padding) tokens
-    of the self-attention sequence — padding keys are masked out of
+    pos_offset: optional [B] int32 left-padding counts of the
+    self-attention sequence — padding keys are masked out of
     scores/colmax and padding query rows out of rowmax.
-    Returns (y, kv_or_None, stats).
+    Returns (y, kv_or_None, stats, rowmax): rowmax [B, Sq] is the O-site
+    importance, None on the flash prefill path (MCA off there).
     """
     causal = cfg.causal if causal is None else causal
     window = cfg.window if window is None else window
@@ -406,6 +421,8 @@ def gqa_attention(p, cfg, x, *, pos, mca_key=None, causal=None,
     dh = cfg.d_head
     scale = dh ** -0.5
     stats = _zero_stats(cfg.mca.n_tiers)
+    kv_valid = (None if pos_offset is None
+                else jnp.arange(skv)[None] >= pos_offset[:, None])
     # in self-attention, query validity is key validity
     q_valid = kv_valid if kv_x is None else None
     # TP-friendly head grouping: when KV heads can't shard over "model" but
@@ -429,6 +446,17 @@ def gqa_attention(p, cfg, x, *, pos, mca_key=None, causal=None,
     q = apply_rope(q, pos, cfg.rope_theta, cfg.rotary_pct)
     k = apply_rope(k, kv_pos, cfg.rope_theta, cfg.rotary_pct)
     k_cache = k
+    mca_v = cfg.mca.active("v_proj") and mca_key is not None
+    mca_o = cfg.mca.active("o_proj") and mca_key is not None
+    if _use_flash_prefill(cfg, return_kv=return_kv, kv_x=kv_x,
+                          causal=causal, window=window,
+                          mca_on=mca_v or mca_o, nm=nm, s=skv):
+        v_cache = _split_heads(src @ p["wv"], hkv, dh)
+        flat = lambda a: a.reshape(b, skv, -1)              # noqa: E731
+        out = kernel_ops.flash_prefill(flat(q), flat(k), flat(v_cache),
+                                       pos_offset, scale=scale,
+                                       n_kv_heads=hkv)
+        return out @ p["wo"], (k_cache, v_cache), stats, None
     if repeat_kv:
         k = jnp.repeat(k, g, axis=2)
         hkv_eff, g_eff = cfg.n_heads, 1
@@ -452,7 +480,6 @@ def gqa_attention(p, cfg, x, *, pos, mca_key=None, causal=None,
         qg = constrain_heads(qg, head_dims=(2, 3))
 
     chunk = pick_chunk(skv, cfg.attn_chunk)
-    mca_v = cfg.mca.active("v_proj") and mca_key is not None
     # the banded gather path has no padding-mask support; fall back to the
     # chunked passes for ragged (left-padded) batches
     banded = _use_banded(cfg, window, skv, causal, kv_x) and kv_valid is None
@@ -507,7 +534,7 @@ def gqa_attention(p, cfg, x, *, pos, mca_key=None, causal=None,
         rowmax = jnp.where(q_valid, rowmax, 0.0)
 
     out = out.reshape(b, sq, cfg.n_heads * dh)
-    if cfg.mca.active("o_proj") and mca_key is not None:
+    if mca_o:
         with jax.named_scope("mca"):
             y, s_o = mca_project(jax.random.fold_in(mca_key, 2), out,
                                  p["wo"], rowmax, sq, cfg.mca, "o_proj")

@@ -50,8 +50,9 @@ Robustness (see ROADMAP.md § Robustness):
 
 Serving metrics land in the ``repro.obs`` registry: ``serve.prefill_seconds``,
 ``serve.decode_step_seconds``, ``serve.generated_tokens``,
-``serve.prefill_tokens``, ``serve.insertions``,
-``serve.prefill_tokens_saved``, ``serve.slot_steps`` and
+``serve.prefill_tokens``, ``serve.insertions`` (of which
+``serve.insert_kernel_insertions`` ran their attention on the flash
+kernel), ``serve.prefill_tokens_saved``, ``serve.slot_steps`` and
 ``serve.slot_idle_steps`` (live-slot occupancy over any window is
 1 - their deltas' ratio), ``serve.flops_reduction``,
 ``serve.tier_occupancy.t{i}``, per-wave ``serve.wave_seconds``, admission
@@ -164,8 +165,15 @@ class Engine:
             def prefill_into(params, prompt, pos_offset, cache, tok, t,
                              steps_left, slot, new_steps):
                 batch_in = {"tokens": prompt, "pos_offset": pos_offset}
+                # attention runs on the flash kernel or the scan, decided
+                # from shapes once per traced bucket: note which
+                calls = obs.get_registry().counter(
+                    "kernels.flash_attention.kernel_calls")
+                before = calls.value
                 new_cache, hidden, stats = model.prefill(params, batch_in,
                                                          max_len, key)
+                self._insert_on_kernel[(key is not None, prompt.shape[1])] = (
+                    calls.value > before)
                 logits = _logits(params, cfg, hidden[:, -1:])
                 cache = cache_insert_slot(cache, new_cache, slot)
                 tok0 = jnp.argmax(logits[..., :cfg.vocab_size],
@@ -189,6 +197,9 @@ class Engine:
                                else make_prefill(None))
         self._decode = jax.jit(decode, donate_argnums=(2,))
         self._decode_step = jax.jit(decode_step, donate_argnums=(2, 3, 4))
+        # (MCA on, bucket) -> whether that insertion program's attention
+        # runs on the flash kernel (set when the bucket is traced)
+        self._insert_on_kernel: Dict = {}
         self._prefill_into = make_prefill_into(self.key)
         self._prefill_into_exact = (self._prefill_into if self.key is None
                                     else make_prefill_into(None))
@@ -343,6 +354,9 @@ class Engine:
             logits = jax.block_until_ready(logits)
             state = SlotState(cache, tok, t, steps_left)
             reg.counter("serve.insertions").inc()
+            reg.counter("serve.insert_kernel_insertions").inc(
+                int(self._insert_on_kernel[(mca and self.key is not None,
+                                            s_pad)]))
             reg.counter("serve.prefill_tokens").inc(s_pad)
             self._record_mca(stats, 1.0)
             try:

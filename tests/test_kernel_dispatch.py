@@ -138,3 +138,130 @@ def test_dispatch_counters_recorded():
                         causal=False, block_q=64, block_k=32)
         assert reg.counter(
             "kernels.flash_attention.fallback_calls").value == 1
+
+
+# ------------------------------------- prefill attention: kernel or scan
+def _gqa128(**over):
+    """A tiny GQA decoder whose heads are whole lanes (d_head 128)."""
+    from repro.configs import get_config
+    from repro.models import build_model, reduced
+    cfg = reduced(get_config("starcoder2-3b"), n_layers=2, d_head=128,
+                  vocab_size=128, **over)
+    model = build_model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(0))
+
+
+def _flash_calls(fn):
+    """Flash kernel call sites a fresh trace of ``fn`` dispatches."""
+    with obs.scoped() as reg:
+        jax.block_until_ready(fn())
+        return reg.counter("kernels.flash_attention.kernel_calls").value
+
+
+def _tokens(cfg, b, s, seed=0):
+    return jax.random.randint(jax.random.PRNGKey(seed), (b, s), 1,
+                              cfg.vocab_size)
+
+
+@pytest.mark.parametrize("s,on_kernel", [(64, False), (128, True),
+                                         (256, True), (192, False)])
+def test_prefill_dispatch_by_bucket(s, on_kernel):
+    """A cache-filling prefill runs attention on the flash kernel where
+    the bucket tiles (128 or more, whole tiles), else on the scan."""
+    cfg, model, params = _gqa128()
+    batch = {"tokens": _tokens(cfg, 1, s),
+             "pos_offset": jnp.asarray([s // 4], jnp.int32)}
+    calls = _flash_calls(lambda: jax.jit(
+        lambda p: model.prefill(p, batch, s + 8)[1])(params))
+    assert (calls > 0) == on_kernel
+
+
+@pytest.mark.parametrize("case,on_kernel", [
+    ("mca_on", False), ("mca_configured_exact", True),
+    ("window_narrower", False), ("window_wider", True)])
+def test_prefill_dispatch_documented_paths(case, on_kernel):
+    """MCA on V/O keeps the scan (it needs the softmax statistics); MCA
+    configured but run exact (no key) takes the kernel; a window narrower
+    than the sequence keeps the scan, one as wide does not matter."""
+    from repro.core.policy import MCAConfig
+    s = 128
+    over = {"mca_on": {"mca": MCAConfig(enabled=True, alpha=0.3, block=16,
+                                        sites=("v_proj", "o_proj"))},
+            "window_narrower": {"window": 64},
+            "window_wider": {"window": 256}}.get(case, {})
+    if case == "mca_configured_exact":
+        over = over or {"mca": MCAConfig(enabled=True, alpha=0.3, block=16,
+                                         sites=("v_proj", "o_proj"))}
+    cfg, model, params = _gqa128(**over)
+    key = jax.random.PRNGKey(1) if case == "mca_on" else None
+    batch = {"tokens": _tokens(cfg, 1, s)}
+    calls = _flash_calls(lambda: jax.jit(
+        lambda p: model.prefill(p, batch, s + 8, key)[1])(params))
+    assert (calls > 0) == on_kernel
+
+
+def test_training_dispatches_no_flash_call():
+    """The training loss and its gradient never take the forward-only
+    kernel, even at a sequence that tiles."""
+    cfg, model, params = _gqa128()
+    toks = _tokens(cfg, 2, 128)
+    batch = {"tokens": toks, "labels": toks}
+    grad = jax.jit(jax.grad(lambda p: model.loss(p, batch, None)[0]))
+    with obs.scoped() as reg:
+        jax.block_until_ready(grad(params))
+        assert reg.counter("kernels.flash_attention.kernel_calls").value == 0
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_prefill_kernel_logits_match_scan(dtype, monkeypatch):
+    """Prefill logits and the cached K/V of real positions on the kernel
+    path match the scan's (``onepass_attention``) for a left-padded batch
+    (pad positions hold K/V that every read masks out)."""
+    from repro.models import attention
+    from repro.models.api import _logits
+    cfg, model, params = _gqa128(dtype=dtype)
+    s = 256
+    batch = {"tokens": _tokens(cfg, 2, s, seed=2),
+             "pos_offset": jnp.asarray([0, 77], jnp.int32)}
+
+    def run():
+        cache, hid, _ = jax.jit(
+            lambda p: model.prefill(p, batch, s + 8))(params)
+        kv = cache["layers"]
+        real = [kv[n][:, r, off:s] for n in ("k", "v")
+                for r, off in enumerate((0, 77))]
+        return _logits(params, cfg, hid[:, -1:]), real
+
+    assert _flash_calls(lambda: run()[0]) > 0
+    got, got_cache = run()
+    monkeypatch.setattr(attention, "_use_flash_prefill",
+                        lambda *a, **k: False)
+    assert _flash_calls(lambda: run()[0]) == 0
+    want, want_cache = run()
+    tol = 1e-4 if dtype == "float32" else 2 * float(
+        jnp.finfo(jnp.bfloat16).eps)
+    for a, b in zip(jax.tree.leaves((got, got_cache)),
+                    jax.tree.leaves((want, want_cache))):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b)
+
+
+def test_generate_left_padded_batch_on_kernel_matches_solo():
+    """``Engine.generate``'s batched left-padded prefill takes the kernel,
+    one offset per row, and each row generates what it would alone (on
+    the scan: 100 positions do not tile)."""
+    from repro.serve import Engine
+    cfg, model, params = _gqa128()
+    eng = Engine(model, params, batch_size=2, max_len=160)
+    rng = np.random.default_rng(0)
+    a = rng.integers(1, cfg.vocab_size, 128).astype(np.int32)
+    b = rng.integers(1, cfg.vocab_size, 100).astype(np.int32)
+    padded = np.stack([a, np.pad(b, (28, 0))])
+    with obs.scoped() as reg:
+        out = eng.generate(padded, max_new=4,
+                           prompt_lens=np.asarray([128, 100]))
+        assert reg.counter("kernels.flash_attention.kernel_calls").value > 0
+    with obs.scoped() as reg:
+        solo_b = eng.generate(np.stack([b, b]), max_new=4)[0]
+        assert reg.counter("kernels.flash_attention.kernel_calls").value == 0
+    np.testing.assert_array_equal(out[1], solo_b)

@@ -138,3 +138,50 @@ def test_sampled_error_shrinks_with_r():
 
     e1, e2, e4 = mean_err(1), mean_err(2), mean_err(4)
     assert e2 < e1 and e4 < e2, (e1, e2, e4)
+
+
+# ------------------------------------------------------ causal prefill
+def _prefill_tiles(s, bq, bk, offs):
+    """Score tiles of one kv head's query-head group the padded causal
+    kernel must compute: below the diagonal, past each row's padding."""
+    return sum(1 for off in offs for i in range(s // bq)
+               for j in range(s // bk)
+               if j * bk <= i * bq + bq - 1 and j * bk + bk > off
+               and i * bq + bq > off)
+
+
+@pytest.mark.parametrize("s,bq,bk", [(128, 128, 128), (256, 128, 128),
+                                     (512, 256, 128), (512, 128, 256)])
+@pytest.mark.parametrize("g", [1, 3])
+@pytest.mark.parametrize("pad", ["none", "in_tile", "whole_tiles"])
+def test_flash_prefill_matches_onepass(s, bq, bk, g, pad):
+    """The padded causal GQA prefill kernel against the scan passes with
+    ``kv_valid``, bf16 inputs: real rows agree within bf16 round-off, pad
+    rows come out finite (zero), and the kernel computes exactly the tiles
+    left after causal and padding skips."""
+    from repro.kernels.flash_attention import flash_prefill
+    from repro.kernels.telemetry import LANE_COUNT
+    from repro.models.attention import onepass_attention
+    hkv, dh, b = 2, 128, 2
+    first = {"none": 0, "in_tile": 37, "whole_tiles": s // 2 + 5}[pad]
+    offs = jnp.asarray([first, first // 3], jnp.int32)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(s + g), 3)
+    bf = jnp.bfloat16
+    q = jax.random.normal(kq, (b, s, hkv, g, dh), bf)
+    k = jax.random.normal(kk, (b, s, hkv, dh), bf)
+    v = jax.random.normal(kv, (b, s, hkv, dh), bf)
+    scale = dh ** -0.5
+    out, tel = flash_prefill(
+        q.reshape(b, s, -1), k.reshape(b, s, -1), v.reshape(b, s, -1), offs,
+        n_kv_heads=hkv, scale=scale, block_q=bq, block_k=bk, interpret=True,
+        telemetry=True)
+    valid = jnp.arange(s)[None] >= offs[:, None]
+    ref, _, _ = onepass_attention(q, k, v, scale=scale, causal=True,
+                                  window=0, chunk=64, kv_valid=valid)
+    got = np.asarray(out, np.float32).reshape(b, s, -1)
+    want = np.asarray(ref, np.float32).reshape(b, s, -1)
+    real = np.asarray(valid)
+    np.testing.assert_allclose(got[real], want[real], rtol=2e-2, atol=2e-2)
+    assert np.all(got[~real] == 0.0)
+    assert int(tel[0, LANE_COUNT]) == hkv * g * _prefill_tiles(
+        s, bq, bk, [int(o) for o in offs])

@@ -177,6 +177,36 @@ def test_slot_batcher_parity_vs_solo_and_wave(engine_setup):
         assert wdone[100 + i] == got[i], f"wave vs per-slot drift, req {i}"
 
 
+def test_slot_batcher_kernel_buckets_parity_and_count():
+    """With whole-lane heads, insertions at buckets of 128 and 256 run
+    attention on the flash kernel and one at 64 on the scan: each request
+    still generates what it does alone (solo prefills at 100 and 130
+    positions run the scan), and ``serve.insert_kernel_insertions``
+    counts the two kernel-path insertions, never the scan's."""
+    from repro import obs
+    from repro.serve import SlotBatcher
+    cfg = reduced(get_config("starcoder2-3b"), n_layers=2, vocab_size=128,
+                  d_head=128)
+    model = build_model(cfg)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)), batch_size=2,
+                 max_len=272)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, cfg.vocab_size, n).astype(np.int32)
+               for n in (100, 130, 60)]
+    assert [eng.prefill_bucket(len(p), 5) for p in prompts] == [128, 256, 64]
+    want = {i: eng.generate(np.stack([p, p]), 5)[0].tolist()
+            for i, p in enumerate(prompts)}
+    with obs.scoped() as reg:
+        sb = SlotBatcher(eng, check_every=3)
+        for i, p in enumerate(prompts):
+            sb.submit(Request(uid=i, prompt=p, max_new=5))
+        got = sb.run()
+        c = reg.snapshot()["counters"]
+    assert got == want
+    assert c["serve.insertions"] == 3
+    assert c["serve.insert_kernel_insertions"] == 2
+
+
 def test_slot_batcher_metrics(engine_setup):
     """Insertion counters: one batch=1 prefill per request, tokens saved
     vs the wave batcher accounted, idle-slot steps and live-slot
@@ -196,6 +226,8 @@ def test_slot_batcher_metrics(engine_setup):
     assert all(len(done[i]) == 4 for i in range(3))
     c = snap["counters"]
     assert c["serve.insertions"] == 3                 # one prefill each
+    # d_head 32 is not whole lanes: every bucket runs the scan, and says so
+    assert c["serve.insert_kernel_insertions"] == 0
     assert c["serve.requests_completed"] == 3
     assert c["serve.generated_tokens"] == 3 * 4
     # prompts pad to the 8-bucket; the third insertion happens while one

@@ -24,7 +24,8 @@ from repro.kernels import ops as kernel_ops
 from repro.kernels import cache_update
 from repro.kernels.attn_colmax import attn_colmax
 from repro.kernels.cache_update import kv_slot_update
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (flash_attention, flash_prefill,
+                                           prefill_blocks)
 from repro.kernels.mca_matmul import mca_matmul_fixed, mca_matmul_ragged
 from repro.models import build_model
 from repro.serve import Engine
@@ -146,6 +147,19 @@ def test_flash_attention_compiles(one_chip):
         scale=D_HEAD ** -0.5, interpret=False))
 
 
+@pytest.mark.parametrize("s", [128, 4096])
+def test_flash_prefill_compiles(one_chip, s):
+    """The insertion's attention at its smallest and largest buckets, with
+    the tiles the dispatch picks, fits the kernel's VMEM."""
+    bq, bk = prefill_blocks(s, D_HEAD)
+    _assert_kernel(flash_prefill.lower(
+        _sds(one_chip, (1, s, N_HEADS * D_HEAD), jnp.bfloat16),
+        _sds(one_chip, (1, s, N_KV * D_HEAD), jnp.bfloat16),
+        _sds(one_chip, (1, s, N_KV * D_HEAD), jnp.bfloat16),
+        _sds(one_chip, (1,), jnp.int32), n_kv_heads=N_KV,
+        scale=D_HEAD ** -0.5, block_q=bq, block_k=bk, interpret=False))
+
+
 def test_attn_colmax_compiles(one_chip):
     _assert_kernel(attn_colmax.lower(
         _sds(one_chip, (1, N_HEADS, SEQ, D_HEAD), jnp.bfloat16),
@@ -165,6 +179,43 @@ def _burst(one_chip, n_layers=2, slots=SLOTS):
     vec = _sds(one_chip, (slots,), jnp.int32)
     return eng._make_burst(8, None).lower(
         params, _sds(one_chip, (slots, 1), jnp.int32), cache, vec, vec)
+
+
+def _insertion(one_chip, s, n_layers=2, slots=SLOTS):
+    """The slot insertion program (batch-1 prefill spliced into a slot)
+    of an ``n_layers`` StarCoder2-3B cut at bucket ``s``; returns it
+    compiled, and whether its attention took the flash kernel."""
+    model = build_model(get_config("starcoder2-3b", n_layers=n_layers))
+    eng = Engine(model, None, batch_size=slots, max_len=MAX_LEN)
+    place = lambda a: _sds(one_chip, a.shape, a.dtype)     # noqa: E731
+    params = jax.tree.map(place, jax.eval_shape(model.init,
+                                                jax.random.PRNGKey(0)))
+    cache = jax.tree.map(place, jax.eval_shape(
+        lambda: model.init_cache(slots, MAX_LEN)))
+    vec = _sds(one_chip, (slots,), jnp.int32)
+    scalar = _sds(one_chip, (), jnp.int32)
+    compiled = eng._prefill_into.lower(
+        params, _sds(one_chip, (1, s), jnp.int32),
+        _sds(one_chip, (1,), jnp.int32), cache,
+        _sds(one_chip, (slots, 1), jnp.int32), vec, vec, scalar,
+        scalar).compile()
+    return compiled, eng._insert_on_kernel[(False, s)]
+
+
+_FLASH = re.compile(r"%flash_prefill[.\d]* = \S+ custom-call\(")
+
+
+@pytest.mark.parametrize("s,on_kernel", [(64, False), (512, True)])
+def test_insertion_attention_dispatch_compiles(one_chip, monkeypatch, s,
+                                               on_kernel):
+    """The insertion program at StarCoder2-3B widths compiles with its
+    attention on the flash kernel where the bucket tiles and on the scan
+    below 128, and the Engine records which (the kernel-insertion
+    counter's source)."""
+    monkeypatch.setattr(kernel_ops, "_interpret", lambda: False)
+    compiled, recorded = _insertion(one_chip, s)
+    assert recorded == on_kernel
+    assert bool(_FLASH.search(compiled.as_text())) == on_kernel
 
 
 _KV_WRITE = re.compile(r"%kv_slot_update[.\d]* = \S+ custom-call\(")
